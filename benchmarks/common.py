@@ -3,6 +3,7 @@ and the ``BENCH_*.json`` artifact writer for the regression gate."""
 from __future__ import annotations
 
 import json
+import os
 import pathlib
 import time
 
@@ -45,22 +46,27 @@ def write_artifact(out_path: str, results: dict) -> None:
     ``compare`` tolerance-bands the ``seconds`` value and ignores the rest,
     while a record WITHOUT ``seconds`` becomes an exact-match contract —
     too brittle for anything derived from timings or platform specifics.
-
-    Every artifact also carries a ``staticcheck_absint`` metadata record:
-    the scale-safety coverage summary (rules, entry points, values
-    analyzed, findings) for the tree the numbers were measured on, so a
-    benchmark result can be traced to a scale-audited build. Its
-    ``seconds`` is pinned at 0.0 — records at 0.0 never trip the timing
-    gate — and the memoized pass costs ~1s once per process.
     """
-    results = dict(results)
-    results.setdefault("staticcheck_absint", _absint_block())
     pathlib.Path(out_path).write_text(json.dumps(results, indent=2))
 
 
-def _absint_block() -> dict:
-    try:
-        from repro.staticcheck.absint_registry import absint_coverage
-        return absint_coverage()
-    except Exception as exc:  # never fail a benchmark run over metadata
-        return {"seconds": 0.0, "error": f"{type(exc).__name__}: {exc}"}
+def device_record() -> dict:
+    """The device a measurement ran on, as JAX reports it."""
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for a script's process.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing else is set. Otherwise the cache lives at ``<repo>/.jax_cache``:
+    a fixed path, since the path is part of each entry's key. Call it from
+    a script's ``main``; importing a library module never turns it on."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(pathlib.Path(__file__).resolve().parent.parent / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -1,8 +1,7 @@
 """Sharded-pipeline benchmark: the end-to-end on-device query path.
 
-Times the three layers of the device-resident multi-shard stack on simulated
-host devices (run in a SUBPROCESS so ``--xla_force_host_platform_device_count``
-is set before jax initializes):
+Times the three layers of the device-resident multi-shard stack over this
+process's devices (``jax.devices()``, up to 4; 2 with ``--fast``):
 
   * ``sharded_neighbor_csr`` — build → ghost exchange → device CSR,
   * ``dbscan_distributed``   — + engine-traversal DBSCAN fixpoint,
@@ -16,54 +15,45 @@ Alongside wall times it records what the device-resident protocol buys:
     matching everything): the dense staging a (q × max_count) gather would
     need vs. the device protocol's ``capacity + (q+1) + q·chunk`` words.
 
-Emits CSV lines plus a ``BENCH_distributed.json`` artifact.
+With fewer than 2 devices it records that it was not run. On a CPU host,
+give the process virtual devices before JAX starts:
 
-  PYTHONPATH=src python -m benchmarks.distributed_pipeline [--fast]
+  XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+      PYTHONPATH=src python -m benchmarks.distributed_pipeline [--fast]
+
+Emits CSV lines plus a ``BENCH_distributed.json`` artifact.
 """
 from __future__ import annotations
 
 import argparse
-import json
-import os
-import pathlib
-import subprocess
-import sys
-import textwrap
 
-_CHILD = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={ndev}"
-    import json, time
-    import numpy as np, jax, jax.numpy as jnp
-    try:  # axis_types only exists on newer JAX
-        mesh = jax.make_mesh(({ndev},), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    except (AttributeError, TypeError):
-        mesh = jax.make_mesh(({ndev},), ("data",))
+import numpy as np
+import jax
+import jax.numpy as jnp
 
-    from benchmarks.common import benchmark_points, timeit
+from benchmarks.common import (benchmark_points, device_record, emit, timeit,
+                               write_artifact)
+
+
+def _stage_times(mesh, ndev: int, n: int, trace_path: str) -> dict:
     from repro.core.distributed import (dbscan_distributed, slab_partition,
                                         sharded_neighbor_csr)
     from repro.halos import halo_pipeline_sharded
 
-    n = {n}
     pts, eps = benchmark_points(n)
-    pts, _ = slab_partition(pts, {ndev})
+    pts, _ = slab_partition(pts, ndev)
     jp = jnp.asarray(pts)
     vel = jnp.asarray(np.random.default_rng(1)
                       .standard_normal((n, 3)).astype(np.float32))
 
-    out = {{}}
-    t = timeit(lambda: sharded_neighbor_csr(
+    out = {}
+    out["neighbor_csr"] = timeit(lambda: sharded_neighbor_csr(
         jp, eps, capacity=32 * n, mesh=mesh, halo_cap=n).indices, iters=2)
-    out["neighbor_csr"] = t
-    t = timeit(lambda: dbscan_distributed(
+    out["dbscan"] = timeit(lambda: dbscan_distributed(
         jp, eps, 2, mesh=mesh, halo_cap=n).labels, iters=2)
-    out["dbscan"] = t
-    t = timeit(lambda: halo_pipeline_sharded(
+    out["pipeline"] = timeit(lambda: halo_pipeline_sharded(
         jp, vel, eps, 2, mesh=mesh, capacity=n, halo_cap=n,
         min_count=2).labels, iters=2)
-    out["pipeline"] = t
 
     # buffered-protocol retry count on the same local problem (the only
     # protocol whose host-sync count is data-dependent).
@@ -85,10 +75,9 @@ _CHILD = textwrap.dedent("""
                          tracer=tracer)
     halo_pipeline_traced(jp, vel, eps, 2, mesh=mesh, capacity=n,
                          halo_cap=n, min_count=2, tracer=tracer)
-    tracer.export({trace_path!r})
+    tracer.export(trace_path)
     out["trace_spans"] = sum(1 for e in tracer.events if e["ph"] == "X")
-    print("JSON:" + json.dumps(out))
-""")
+    return out
 
 
 def _staging_words(q: int, max_count: int, capacity: int, chunk: int) -> dict:
@@ -101,32 +90,27 @@ def _staging_words(q: int, max_count: int, capacity: int, chunk: int) -> dict:
 
 def main(fast: bool = False, out_path: str = "BENCH_distributed.json",
          trace_path: str = "trace_distributed.json") -> None:
-    from benchmarks.common import emit
-
-    ndev = 2 if fast else 4
+    ndev = min(len(jax.devices()), 2 if fast else 4)
     n = 256 if fast else 1024
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.pathsep.join(
-        [str(pathlib.Path(__file__).resolve().parent.parent / "src"),
-         str(pathlib.Path(__file__).resolve().parent.parent),
-         env.get("PYTHONPATH", "")])
-    env.pop("XLA_FLAGS", None)
-    code = _CHILD.format(ndev=ndev, n=n, trace_path=trace_path)
-    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                          text=True, env=env, timeout=1800)
-    if proc.returncode != 0:
-        raise RuntimeError(proc.stderr[-3000:])
-    child = json.loads(proc.stdout.strip().rsplit("JSON:", 1)[1])
+    if ndev < 2:
+        emit("distributed/pipeline", 0.0, derived="not_run=1_device")
+        write_artifact(out_path, {"distributed/not_run": {
+            "seconds": 0.0, "reason": "not run on 1 device",
+            "device": device_record()}})
+        return
+    mesh = jax.make_mesh((ndev,), ("data",), devices=jax.devices()[:ndev],
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    stages = _stage_times(mesh, ndev, n, trace_path)
 
     results: dict = {}
     for stage in ("neighbor_csr", "dbscan", "pipeline"):
-        t = child[stage]
+        t = stages[stage]
         name = f"distributed/{stage}_n{n}_s{ndev}"
         emit(name, t, derived=f"shards={ndev};points_per_s={n / max(t, 1e-12):.0f}")
         results[name] = {"seconds": t, "n": n, "shards": ndev, "stage": stage}
 
     # host syncs per CSR query, by output protocol
-    syncs = {"two_pass": 1, "buffered": child["buffered_attempts"], "device": 0}
+    syncs = {"two_pass": 1, "buffered": stages["buffered_attempts"], "device": 0}
     for proto, k in syncs.items():
         emit(f"distributed/host_syncs_{proto}", 0.0, derived=f"syncs={k}")
     results["distributed/host_syncs"] = syncs
@@ -142,9 +126,10 @@ def main(fast: bool = False, out_path: str = "BENCH_distributed.json",
     results["distributed/staging_words"] = {"skewed": skew, "uniform": unif}
 
     emit("distributed/trace_spans", 0.0,
-         derived=f"spans={child['trace_spans']};file={trace_path}")
+         derived=f"spans={stages['trace_spans']};file={trace_path}")
 
-    pathlib.Path(out_path).write_text(json.dumps(results, indent=2))
+    results["distributed/device"] = {"seconds": 0.0, **device_record()}
+    write_artifact(out_path, results)
 
 
 if __name__ == "__main__":

@@ -1,13 +1,12 @@
 """Microbenchmarks for the Pallas kernels and their jnp references.
 
-By default the kernels run in interpret mode on CPU — relative numbers
-only; the interesting derived number there is ref-vs-kernel agreement +
-the work scaling, while absolute us/call is backend-specific. On a real
-TPU, set ``REPRO_TPU=1`` to time the natively-compiled kernels instead
-(pairwise MXU epilogue, segmented reductions, and the wavefront
-traversal) — real-hardware numbers slot in without code changes. The
-mode actually used is recorded in the artifact's ``kernels/mode`` record
-so a baseline can never silently mix the two.
+The kernels compile natively on a TPU and run in interpret mode anywhere
+else (``kernels.pairwise.INTERPRET``); interpret-mode times measure the
+Pallas interpreter, not a chip, so they are relative numbers only. The
+``kernels/mode`` record names the device and the mode, so a baseline can
+never silently mix the two. The wavefront traversal has no native
+lowering yet (``kernels.wavefront.LOWERING_GAP``), so on a TPU its record
+says it was not run.
 
 Emits the usual CSV lines plus a ``BENCH_kernels.json`` artifact (kernel
 and reference timings per size) for the ``benchmarks.compare``
@@ -15,27 +14,19 @@ regression gate.
 """
 from __future__ import annotations
 
-import os
-
 import numpy as np
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref, segment
-from benchmarks.common import benchmark_points, emit, timeit, write_artifact
-
-# REPRO_TPU=1 opts into native compilation; anything else keeps the
-# CPU-safe interpret path (also the right choice on a TPU host when you
-# want apples-to-apples numbers against an interpret baseline).
-NATIVE_TPU = os.environ.get("REPRO_TPU") == "1"
-INTERPRET = not NATIVE_TPU
+from repro.kernels.pairwise import INTERPRET
+from benchmarks.common import (benchmark_points, device_record, emit, timeit,
+                               write_artifact)
 
 
 def _mode_record() -> dict:
     # seconds pinned at 0.0: compare never gates on this record, it only
     # documents how the numbers alongside it were produced.
-    return {"seconds": 0.0, "interpret": INTERPRET, "native_tpu": NATIVE_TPU,
-            "jax_backend": jax.default_backend()}
+    return {"seconds": 0.0, "interpret": INTERPRET, "device": device_record()}
 
 
 def _bench_pairwise(results: dict) -> None:
@@ -44,10 +35,8 @@ def _bench_pairwise(results: dict) -> None:
         x = jnp.asarray(rng.uniform(0, 1, (n, d)), jnp.float32)
         eps = 0.1
         t_ref = timeit(lambda: ref.pairwise_count_ref(x, x, eps * eps))
-        t_k = timeit(lambda: ops.eps_neighbor_counts(x, x, eps,
-                                                     interpret=INTERPRET))
-        got = np.asarray(ops.eps_neighbor_counts(x, x, eps,
-                                                 interpret=INTERPRET))
+        t_k = timeit(lambda: ops.eps_neighbor_counts(x, x, eps))
+        got = np.asarray(ops.eps_neighbor_counts(x, x, eps))
         want = np.asarray(ref.pairwise_count_ref(x, x, eps * eps))
         # pairs within ~1e-5 relative of eps are float knife-edges: the
         # kernel's expanded-form distance can round across the threshold.
@@ -66,11 +55,9 @@ def _bench_segment(results: dict) -> None:
         seg = np.sort(rng.integers(0, nseg, n)).astype(np.int32)
         data = jnp.asarray(rng.normal(size=(n, 4)), jnp.float32)
         seg = jnp.asarray(seg)
-        t_k = timeit(lambda: segment.segment_sum_sorted(
-            data, seg, nseg, interpret=INTERPRET))
+        t_k = timeit(lambda: segment.segment_sum_sorted(data, seg, nseg))
         t_ref = timeit(lambda: ref.segment_sum_sorted_ref(data, seg, nseg))
-        got = np.asarray(segment.segment_sum_sorted(data, seg, nseg,
-                                                    interpret=INTERPRET))
+        got = np.asarray(segment.segment_sum_sorted(data, seg, nseg))
         want = np.asarray(ref.segment_sum_sorted_ref(data, seg, nseg))
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
         emit(f"kernel_segment_sum_n{n}_s{nseg}", t_k,
@@ -85,14 +72,16 @@ def _bench_wavefront(results: dict) -> None:
     from repro.core.query import query_count, within
 
     n = 1024
+    if not INTERPRET:
+        emit(f"kernel_wavefront_count_n{n}", 0.0, "not_run=no_native_lowering")
+        results[f"kernels/wavefront_count_n{n}"] = {
+            "seconds": 0.0, "n": n, "not_run": "no native lowering"}
+        return
     pts, eps = benchmark_points(n)
     jp = jnp.asarray(pts)
     lo, hi = scene_bounds(jp)
     bvh = build_bvh(jp, lo, hi)
     pred = within(jp, eps)
-    # The engine picks interpret-vs-native from the backend (kernels.ops.
-    # INTERPRET); under REPRO_TPU=1 on a TPU host that IS native — the mode
-    # record above documents which one this run measured.
     t_k = timeit(lambda: query_count(bvh, pred, backend="pallas",
                                      sort_queries=True), iters=2)
     t_ref = timeit(lambda: query_count(bvh, pred, backend="stackless",

@@ -11,10 +11,10 @@ so the cost of each output protocol is tracked per backend:
              timed with a capacity that holds, i.e. the zero-retry
              common case),
   backends:  stackless (rope), stack, and the Pallas wavefront kernel
-             (interpret mode on CPU — the column tracks dispatch/padding
-             overhead there; native timings need a TPU, see
-             benchmarks/kernels_micro.py and REPRO_TPU=1), plus the pair
-             backend's fused count for the self-join workloads.
+             (interpret mode off the TPU — the column tracks dispatch/
+             padding overhead there; it has no native lowering yet, so a
+             TPU run leaves it out), plus the pair backend's fused count
+             for the self-join workloads.
 
 Emits the usual CSV lines plus a ``BENCH_query.json`` artifact so CSR
 two-pass vs. fused-callback cost rides along the existing benches.
@@ -33,6 +33,7 @@ from repro.core.bvh import build_bvh
 from repro.core.geometry import scene_bounds
 from repro.core.query import (query, query_count, query_csr,
                               query_csr_buffered, query_csr_device, within)
+from repro.kernels.pairwise import INTERPRET
 
 
 def _grid(n: int, results: dict) -> None:
@@ -50,7 +51,7 @@ def _grid(n: int, results: dict) -> None:
             return c + 1, jnp.bool_(False)
         return query(bvh, pred, cb, jnp.int32(0), backend="pair")
 
-    backends = ("stackless", "stack", "pallas")
+    backends = ("stackless", "stack") + (("pallas",) if INTERPRET else ())
     runs = [("count", b, lambda b=b: query_count(bvh, pred, backend=b))
             for b in backends]
     runs += [("csr_two_pass", b,

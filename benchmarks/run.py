@@ -17,6 +17,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from benchmarks.common import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (distributed_pipeline, fig1_insitu, fig4_timeline,
                             halo_pipeline, kernels_micro, query_micro,
                             roofline_report, table1_morton)

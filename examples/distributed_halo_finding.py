@@ -21,11 +21,7 @@ from repro.core.distributed import dbscan_distributed, slab_partition
 from repro.core.ref_numpy import core_mask_ref, dbscan_ref, labels_equivalent
 from repro.data.pipeline import hacc_benchmark_epsilon, make_clustered_points
 
-try:  # axis_types only exists on newer JAX
-    mesh = jax.make_mesh((8,), ("data",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
-except (AttributeError, TypeError):
-    mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 n = 1024
 pts = make_clustered_points(np.random.default_rng(1), n)
 eps = hacc_benchmark_epsilon(1.0, n)

@@ -12,9 +12,10 @@ Faithful tier (GPU-paper semantics, validated against the numpy oracle):
   functionality surface. ``traversal`` keeps the pre-engine entry points
   as compatibility shims.
 
-TPU-native tier (the production path):
-  cell_grid + fdbscan_grid (tiled ε-stencil DBSCAN on the MXU, backed by
-  repro.kernels.pairwise), distributed (shard_map multi-device DBSCAN).
+Also: cell_grid + fdbscan_grid (tiled ε-stencil DBSCAN on the MXU, backed
+by repro.kernels.pairwise; a dense ε-grid, so only for small boxes — about
+4e8 cells at n = 2^21 under the paper's ε), and distributed (shard_map
+multi-device DBSCAN). The halo-finding path on a TPU is ``fdbscan``.
 """
 from repro.core.bvh import Bvh, build_bvh, build_bvh_objects, SENTINEL
 from repro.core.cell_grid import CellGrid, build_cell_grid, cell_box

@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.bvh import Bvh, build_bvh
 from repro.core.dbscan import count_neighbors, min_core_label_on, union_rounds
@@ -311,10 +310,10 @@ def _neighbor_csr_sharded(points, eps, capacity, halo_cap, axis, mesh_ref,
                 (ovf | halo_ovf)[None])
 
     spec_in = P(axis, None)
-    offsets, indices, total, ovf = shard_map(
+    offsets, indices, total, ovf = jax.shard_map(
         local_fn, mesh=mesh, in_specs=(spec_in,),
         out_specs=(P(axis, None), P(axis, None), P(axis), P(axis)),
-        check_rep=False,
+        check_vma=False,
     )(points.reshape(n_shards, -1, points.shape[-1]))
     return offsets, indices, total, jnp.any(ovf)
 
@@ -442,12 +441,12 @@ def _dbscan_sharded(points, eps, min_pts, halo_cap, axis, mesh_ref, max_rounds,
                 ctx.exchange.overflow[None])
 
     spec_in = P(axis, None)
-    # check_rep=False: the body contains while_loops (union fixpoints), for
-    # which shard_map has no replication rule on some JAX versions.
-    labels, core, rounds, ovf = shard_map(
+    # check_vma=False: the union fixpoints' while_loop carries mix per-shard
+    # values with psum'd (shard-invariant) ones.
+    labels, core, rounds, ovf = jax.shard_map(
         local_fn, mesh=mesh, in_specs=(spec_in,),
         out_specs=(P(axis), P(axis), P(axis), P(axis)),
-        check_rep=False,
+        check_vma=False,
     )(points.reshape(n_shards, -1, points.shape[-1]))
     return (labels.reshape(-1), core.reshape(-1), jnp.max(rounds),
             jnp.any(ovf))

@@ -1,7 +1,11 @@
-"""TPU-native FDBSCAN (DESIGN.md §2): ε-cell binning + MXU stencil kernels.
+"""Grid FDBSCAN (DESIGN.md §2): ε-cell binning + MXU stencil kernels.
 
 The faithful tier (``dbscan.py``) reproduces ArborX's SIMT algorithms; this
-module is the *production* path on TPU. It keeps the paper's insight —
+module is a tiled alternative for small boxes, NOT the production path: its
+grid is dense, one cell per ε³ of the scene, so the paper's ε convention
+needs about 4e8 cells at n = 2^21 (and the neighbor map must fit the
+kernels' 1 MiB of SMEM). The halo-finding path is ``dbscan.fdbscan``. It
+keeps the paper's insight —
 spatially sort, test only geometrically adjacent candidates, fuse the
 user operation into the traversal so neighbor lists are never materialized —
 but expresses it as dense tile algebra:

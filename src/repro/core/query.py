@@ -66,6 +66,7 @@ import jax.numpy as jnp
 from repro.core.bvh import Bvh, SENTINEL
 from repro.core.geometry import aabb_aabb_dist2, point_aabb_dist2
 from repro.core.morton import morton32, normalize_points, sort_by_morton32
+from repro.kernels.pairwise import INTERPRET
 from repro.obs.stats import TraversalStats
 
 __all__ = [
@@ -161,7 +162,7 @@ def _canon_index_dtype(index_dtype):
     if dt == jnp.dtype(jnp.int64) and not jax.config.jax_enable_x64:
         raise ValueError(
             "index_dtype=int64 requires x64 mode "
-            "(jax.experimental.enable_x64() or jax_enable_x64=True); "
+            "(jax.enable_x64(True) or jax_enable_x64=True); "
             "without it JAX silently truncates to int32 and CSR offsets "
             "overflow once total hits exceed 2^31")
     return dt
@@ -910,7 +911,8 @@ def query(bvh: Bvh, predicates, callback: Callable | None = None,
     * ``Within`` / ``IntersectsBox`` + callback -> per-query final carries.
       ``backend``: ``stackless`` | ``stack`` | ``pallas`` (the wavefront
       kernel — a block of queries per grid step advances the rope
-      traversal in lockstep; interpret mode on CPU, native on TPU) |
+      traversal in lockstep; interpret mode off the TPU, and on a TPU a
+      ``NotImplementedError`` naming the missing Mosaic lowering) |
       ``pair`` (self-join; carries in sorted leaf order, see
       ``_pair_query``).
     * ``Nearest`` -> ``NearestResult`` (or carries, if a callback is given:
@@ -934,6 +936,11 @@ def query(bvh: Bvh, predicates, callback: Callable | None = None,
     the per-query traversal entry node — the cell-grid pruned variants
     start queries below the root.
     """
+    if backend == "pallas":
+        # Raise here, before any tracing, where the kernel would compile
+        # natively (a TPU): it has no Mosaic lowering yet.
+        from repro.kernels.wavefront import require_lowering
+        require_lowering(INTERPRET)
     if with_stats and (isinstance(predicates, Nearest)
                        or (isinstance(predicates, Ray) and callback is None)):
         raise ValueError(

@@ -36,7 +36,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.halos import catalog as _cat
 from repro.halos.catalog import HaloCatalog, NOISE, _sort_last
@@ -177,10 +176,10 @@ def halo_catalog_sharded(points: jax.Array, velocities: jax.Array,
         num_halos=rep, overflow=rep, root=rep, count=rep, mass=rep,
         center=rep, vmean=rep, vdisp=rep, rmax=rep, particle_halo=P(axis))
     spec = P(axis, None)
-    cat = shard_map(
+    cat = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(spec, spec, P(axis, None)),
-        out_specs=out_specs, check_rep=False,
+        out_specs=out_specs, check_vma=False,
     )(points.reshape(n_shards, -1, points.shape[-1]),
       velocities.reshape(n_shards, -1, velocities.shape[-1]),
       labels.reshape(n_shards, -1))
@@ -270,9 +269,9 @@ def _pipeline_sharded(points, velocities, eps, min_pts, capacity, halo_cap,
         from repro.halos.so_mass import SoMassResult
         out_specs = out_specs + (SoMassResult(rep, rep, rep, rep),)
     spec = P(axis, None)
-    res = shard_map(
+    res = jax.shard_map(
         local_fn, mesh=mesh, in_specs=(spec, spec), out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(points.reshape(n_shards, -1, points.shape[-1]),
       velocities.reshape(n_shards, -1, velocities.shape[-1]))
     labels, core, rounds, ovf, cat = res[:5]

@@ -11,8 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import pairwise as _k
-
-INTERPRET = jax.default_backend() != "tpu"
+from repro.kernels.pairwise import INTERPRET
 
 __all__ = [
     "eps_neighbor_counts",

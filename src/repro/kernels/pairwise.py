@@ -43,8 +43,13 @@ from jax.experimental.pallas import tpu as pltpu
 BIG = 1e15  # padding coordinate; BIG**2 is finite in f32, so no NaNs
 SENTINEL_LABEL = jnp.iinfo(jnp.int32).max
 
+# Pallas kernels compile natively on a TPU and run in interpret mode on any
+# other backend (same numerics; what the CPU test suite runs).
+INTERPRET = jax.default_backend() != "tpu"
+
 __all__ = [
     "BIG",
+    "INTERPRET",
     "SENTINEL_LABEL",
     "pairwise_count",
     "pairwise_min_label",
@@ -58,9 +63,15 @@ def _dist2_tile(x, y):
     xx = jnp.sum(x * x, axis=-1, keepdims=True)            # (TM, 1)
     yy = jnp.sum(y * y, axis=-1)[None, :]                  # (1, TN)
     xy = jax.lax.dot_general(x, y, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
     return xx + yy - 2.0 * xy
 
+
+# Every kernel below tiles distances CANDIDATES x QUERIES, so per-query
+# results reduce over sublanes into a (1, TM) lane row: outputs are laid out
+# (1, m) and per-candidate payloads (labels, core flags) as (n, 1) columns.
+# No block is a 1-D int32 vector, whose HBM tiling Mosaic does not share.
 
 # ---------------------------------------------------------------------------
 # All-pairs kernels: grid (M/TM, N/TN), accumulate over axis 1
@@ -71,9 +82,9 @@ def _count_kernel(x_ref, y_ref, eps2_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    d2 = _dist2_tile(x_ref[...], y_ref[...])
+    d2 = _dist2_tile(y_ref[...], x_ref[...])               # (TN, TM)
     hits = (d2 <= eps2_ref[0]).astype(jnp.int32)
-    o_ref[...] += jnp.sum(hits, axis=1)
+    o_ref[...] += jnp.sum(hits, axis=0, keepdims=True)
 
 
 def _minlabel_kernel(x_ref, y_ref, lab_ref, core_ref, eps2_ref, o_ref):
@@ -81,16 +92,16 @@ def _minlabel_kernel(x_ref, y_ref, lab_ref, core_ref, eps2_ref, o_ref):
     def _init():
         o_ref[...] = jnp.full_like(o_ref, SENTINEL_LABEL)
 
-    d2 = _dist2_tile(x_ref[...], y_ref[...])
-    ok = (d2 <= eps2_ref[0]) & (core_ref[...] != 0)[None, :]
-    cand = jnp.where(ok, lab_ref[...][None, :], SENTINEL_LABEL)
-    o_ref[...] = jnp.minimum(o_ref[...], jnp.min(cand, axis=1))
+    d2 = _dist2_tile(y_ref[...], x_ref[...])               # (TN, TM)
+    ok = (d2 <= eps2_ref[0]) & (core_ref[...] != 0)
+    cand = jnp.where(ok, lab_ref[...], SENTINEL_LABEL)
+    o_ref[...] = jnp.minimum(o_ref[...], jnp.min(cand, axis=0, keepdims=True))
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "tn", "interpret"))
 def pairwise_count(x: jax.Array, y: jax.Array, eps2: jax.Array,
                    *, tm: int = 128, tn: int = 128,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool = INTERPRET) -> jax.Array:
     """counts[i] = |{j : ‖x_i − y_j‖² ≤ eps2}|. Shapes pre-padded to tiles."""
     m, d = x.shape
     n, _ = y.shape
@@ -103,17 +114,17 @@ def pairwise_count(x: jax.Array, y: jax.Array, eps2: jax.Array,
             pl.BlockSpec((tn, d), lambda i, j: (j, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((tm,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((m,), jnp.int32),
+        out_specs=pl.BlockSpec((1, tm), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, m), jnp.int32),
         interpret=interpret,
-    )(x, y, eps2.reshape(1))
+    )(x, y, eps2.reshape(1))[0]
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "tn", "interpret"))
 def pairwise_min_label(x: jax.Array, y: jax.Array, labels: jax.Array,
                        core: jax.Array, eps2: jax.Array,
                        *, tm: int = 128, tn: int = 128,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool = INTERPRET) -> jax.Array:
     """minlab[i] = min over ε-hits j with core[j] of labels[j] (else sentinel)."""
     m, d = x.shape
     n, _ = y.shape
@@ -124,14 +135,15 @@ def pairwise_min_label(x: jax.Array, y: jax.Array, labels: jax.Array,
         in_specs=[
             pl.BlockSpec((tm, d), lambda i, j: (i, 0)),
             pl.BlockSpec((tn, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((tn,), lambda i, j: (j,)),
-            pl.BlockSpec((tn,), lambda i, j: (j,)),
+            pl.BlockSpec((tn, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((tn, 1), lambda i, j: (j, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((tm,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((m,), jnp.int32),
+        out_specs=pl.BlockSpec((1, tm), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, m), jnp.int32),
         interpret=interpret,
-    )(x, y, labels, core.astype(jnp.int32), eps2.reshape(1))
+    )(x, y, labels.reshape(n, 1), core.astype(jnp.int32).reshape(n, 1),
+      eps2.reshape(1))[0]
 
 
 # ---------------------------------------------------------------------------
@@ -144,10 +156,9 @@ def _stencil_count_kernel(nbr_ref, q_ref, c_ref, eps2_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    q = q_ref[0]          # (C, D)
-    c = c_ref[0]          # (C, D)
-    d2 = _dist2_tile(q, c)
-    o_ref[0] += jnp.sum((d2 <= eps2_ref[0]).astype(jnp.int32), axis=1)
+    d2 = _dist2_tile(c_ref[0], q_ref[0])                   # (C, C)
+    o_ref[0] += jnp.sum((d2 <= eps2_ref[0]).astype(jnp.int32), axis=0,
+                        keepdims=True)
 
 
 def _stencil_minlabel_kernel(nbr_ref, q_ref, c_ref, lab_ref, core_ref, eps2_ref, o_ref):
@@ -156,15 +167,24 @@ def _stencil_minlabel_kernel(nbr_ref, q_ref, c_ref, lab_ref, core_ref, eps2_ref,
     def _init():
         o_ref[...] = jnp.full_like(o_ref, SENTINEL_LABEL)
 
-    d2 = _dist2_tile(q_ref[0], c_ref[0])
-    ok = (d2 <= eps2_ref[0]) & (core_ref[0] != 0)[None, :]
-    cand = jnp.where(ok, lab_ref[0][None, :], SENTINEL_LABEL)
-    o_ref[0] = jnp.minimum(o_ref[0], jnp.min(cand, axis=1))
+    d2 = _dist2_tile(c_ref[0], q_ref[0])                   # (C, C)
+    ok = (d2 <= eps2_ref[0]) & (core_ref[0] != 0)
+    cand = jnp.where(ok, lab_ref[0], SENTINEL_LABEL)
+    o_ref[0] = jnp.minimum(o_ref[0], jnp.min(cand, axis=0, keepdims=True))
+
+
+def _cell_spec(cap: int, d: int, s: int | None = None) -> pl.BlockSpec:
+    """One cell's (1, cap, d) block: the grid's own cell, or (given the
+    stencil size ``s``) its candidate read from the prefetched neighbor map,
+    flattened to 1-D so SMEM holds it unpadded."""
+    if s is None:
+        return pl.BlockSpec((1, cap, d), lambda i, j, nbr: (i, 0, 0))
+    return pl.BlockSpec((1, cap, d), lambda i, j, nbr: (nbr[i * s + j], 0, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def stencil_count(cell_pts: jax.Array, nbr_map: jax.Array, eps2: jax.Array,
-                  *, interpret: bool = True) -> jax.Array:
+                  *, interpret: bool = INTERPRET) -> jax.Array:
     """Per-slot ε-neighbor counts over the cell stencil.
 
     cell_pts: (ncells+1, C, D) — slot-padded cells; the LAST cell is all
@@ -179,24 +199,24 @@ def stencil_count(cell_pts: jax.Array, nbr_map: jax.Array, eps2: jax.Array,
         num_scalar_prefetch=1,
         grid=(ncells, s),
         in_specs=[
-            pl.BlockSpec((1, cap, d), lambda i, j, nbr: (i, 0, 0)),
-            pl.BlockSpec((1, cap, d), lambda i, j, nbr: (nbr[i, j], 0, 0)),
+            _cell_spec(cap, d),
+            _cell_spec(cap, d, s),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, cap), lambda i, j, nbr: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, cap), lambda i, j, nbr: (i, 0, 0)),
     )
     return pl.pallas_call(
         _stencil_count_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((ncells, cap), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((ncells, 1, cap), jnp.int32),
         interpret=interpret,
-    )(nbr_map, cell_pts, cell_pts, eps2.reshape(1))
+    )(nbr_map.reshape(-1), cell_pts, cell_pts, eps2.reshape(1))[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def stencil_min_label(cell_pts: jax.Array, cell_labels: jax.Array,
                       cell_core: jax.Array, nbr_map: jax.Array, eps2: jax.Array,
-                      *, interpret: bool = True) -> jax.Array:
+                      *, interpret: bool = INTERPRET) -> jax.Array:
     """Per-slot min label over ε-reachable core points in the stencil.
 
     cell_labels: (ncells+1, C) int32 (sentinel at padding),
@@ -210,18 +230,18 @@ def stencil_min_label(cell_pts: jax.Array, cell_labels: jax.Array,
         num_scalar_prefetch=1,
         grid=(ncells, s),
         in_specs=[
-            pl.BlockSpec((1, cap, d), lambda i, j, nbr: (i, 0, 0)),
-            pl.BlockSpec((1, cap, d), lambda i, j, nbr: (nbr[i, j], 0, 0)),
-            pl.BlockSpec((1, cap), lambda i, j, nbr: (nbr[i, j], 0)),
-            pl.BlockSpec((1, cap), lambda i, j, nbr: (nbr[i, j], 0)),
+            _cell_spec(cap, d),
+            _cell_spec(cap, d, s),
+            _cell_spec(cap, 1, s),
+            _cell_spec(cap, 1, s),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, cap), lambda i, j, nbr: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, cap), lambda i, j, nbr: (i, 0, 0)),
     )
     return pl.pallas_call(
         _stencil_minlabel_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((ncells, cap), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((ncells, 1, cap), jnp.int32),
         interpret=interpret,
-    )(nbr_map, cell_pts, cell_pts, cell_labels, cell_core.astype(jnp.int32),
-      eps2.reshape(1))
+    )(nbr_map.reshape(-1), cell_pts, cell_pts, cell_labels[:, :, None],
+      cell_core.astype(jnp.int32)[:, :, None], eps2.reshape(1))[:, 0]

@@ -24,8 +24,10 @@ arrays, so callers pass a ``make_fns(tree)`` *factory* instead of
 prebuilt ``node_fn``/``leaf_fn`` closures.  The factory is re-invoked
 inside the kernel on a :class:`TreeView` built from kernel-local ref
 reads, giving closures whose captured arrays live in kernel memory.
-On CPU the kernel runs in interpret mode (same numerics, used by CI);
-on TPU it compiles natively.
+Interpret mode only, for now: Mosaic's gather lowering refuses the
+in-kernel per-lane table reads (``tree.rope[node]``,
+``tree.node_lo[node]`` ... inside the ``while_loop``), so a native
+compile raises :data:`LOWERING_GAP` instead of silently interpreting.
 """
 from __future__ import annotations
 
@@ -39,7 +41,8 @@ from repro.core.bvh import SENTINEL
 
 from repro.kernels.ops import INTERPRET, pad_rows, pad_rows_edge, round_up
 
-__all__ = ["BLOCK_Q", "TreeView", "wavefront_traverse", "wavefront_fill_round"]
+__all__ = ["BLOCK_Q", "LOWERING_GAP", "TreeView", "require_lowering",
+           "wavefront_traverse", "wavefront_fill_round"]
 
 # Default queries per grid step. 128 matches the TPU lane width; interpret
 # mode accepts anything.
@@ -49,6 +52,19 @@ BLOCK_Q = 128
 # Pallas kernel may not capture jnp array constants (SENTINEL is a
 # jnp.int32 scalar).
 _SENT = int(SENTINEL)
+
+LOWERING_GAP = (
+    "backend='pallas' has no native TPU lowering: Mosaic's gather lowering "
+    "rule refuses the wavefront kernel's per-lane node-table reads "
+    "(tree.rope[node], tree.left_child[node], tree.node_lo[node] inside its "
+    "while_loop: 'Shape mismatch in input, indices and output'). Use "
+    "backend='stackless' on a TPU (ROADMAP R1).")
+
+
+def require_lowering(interpret: bool) -> None:
+    """Raise :data:`LOWERING_GAP` unless the kernel is to be interpreted."""
+    if not interpret:
+        raise NotImplementedError(LOWERING_GAP)
 
 
 class TreeView(NamedTuple):
@@ -115,6 +131,7 @@ def wavefront_traverse(bvh, qdata, make_fns: Callable, carry_init, *,
     ``(carries, (nodes, aabb, leaf, maxd, done))`` matching the engine's
     ``_stats_from_raw`` layout.
     """
+    require_lowering(interpret)
     leaves = jax.tree.leaves(qdata)
     if not leaves:
         raise ValueError("qdata must contain at least one per-query array")
@@ -259,6 +276,7 @@ def wavefront_fill_round(bvh, qdata, make_fns: Callable,
     Returns ``(node_state, bufs, counts)`` with shapes
     ``(q,), (q, chunk), (q,)``.
     """
+    require_lowering(interpret)
     q = node_state.shape[0]
     chunk = max(int(chunk), 1)
     if q == 0:

@@ -6,12 +6,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across versions: axis_types only exists on newer JAX."""
-    try:
-        auto = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=auto)
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

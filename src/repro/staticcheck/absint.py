@@ -151,7 +151,7 @@ def _is_literal(v) -> bool:
 _SHAPE_ONLY = frozenset((
     "broadcast_in_dim", "reshape", "squeeze", "expand_dims", "transpose",
     "rev", "copy", "stop_gradient", "slice", "device_put",
-    "sharding_constraint", "optimization_barrier"))
+    "sharding_constraint", "optimization_barrier", "reshard"))
 
 # Subset safe for guard-refinement aliasing: lane i of the output is lane i
 # (or a replica) of the input, so a lanewise predicate on the root still
@@ -160,7 +160,7 @@ _SHAPE_ONLY = frozenset((
 _LANE_SAFE = frozenset((
     "broadcast_in_dim", "reshape", "squeeze", "expand_dims", "copy",
     "stop_gradient", "device_put", "sharding_constraint",
-    "optimization_barrier"))
+    "optimization_barrier", "reshard"))
 
 
 def _is_index_use(eqn, var) -> bool:
@@ -187,11 +187,12 @@ class _Interp:
         self.findings: dict = {}     # dedup key -> Finding
         self.report = AbsintReport(name=name, findings=[])
         self.mesh_stack: list = []   # enclosing shard_map meshes
-        # Cross-level guard provenance: jnp.where stages as a pjit whose
-        # select_n sits one jaxpr BELOW the comparison producing its
-        # predicate, so the same-level producer scan cannot refine it.
-        # These maps are keyed by Var object (unique per trace; pjit-cached
-        # inner vars are re-bound at each _sub call before use):
+        # Cross-level guard provenance: jnp.where stages as a nested jit
+        # (primitive ``jit``) whose select_n sits one jaxpr BELOW the
+        # comparison producing its predicate, so the same-level producer
+        # scan cannot refine it. These maps are keyed by Var object (unique
+        # per trace; jit-cached inner vars are re-bound at each _sub call
+        # before use):
         self.guard_of: dict = {}     # cmp outvar -> (op, x_root, const)
         self.lin_of: dict = {}       # add/sub outvar -> (x_root, delta)
         self.alias: dict = {}        # var -> root var (shape-only, bindings)
@@ -394,7 +395,7 @@ class _Interp:
             env[var] = iv if iv is not None else lat.dtype_top(_aval_dtype(var))
             self.val_of[var] = env[var]
         if bind is not None:
-            # 1:1 call-site binding (pjit): alias inner invars to their
+            # 1:1 call-site binding (nested jit): alias inner invars to their
             # outer arguments so guard provenance crosses the jaxpr edge.
             for ivar, ovar in zip(jaxpr.invars, bind):
                 if not _is_literal(ovar):
@@ -472,7 +473,7 @@ class _Interp:
         """Cross-level variant of ``_refine_case``: the guarded var is
         identified by its alias ROOT rather than a same-level producer
         scan, so ``jnp.where(x < c, x, y)`` refines even when the select
-        sits inside a pjit and the cmp in its parent."""
+        sits inside a nested jit and the cmp in its parent."""
         base = self._read(env, case_var)
         if _is_literal(case_var):
             return base
@@ -517,7 +518,7 @@ def _eqn(self: _Interp, env, eqn, ctx):
             self._write(env, var, lat.dtype_top(_aval_dtype(var)))
 
     # --- structured control flow ----------------------------------------
-    if prim in ("pjit", "closed_call", "core_call", "xla_call", "remat_call",
+    if prim in ("jit", "closed_call", "core_call", "xla_call", "remat_call",
                 "remat", "checkpoint", "custom_jvp_call", "custom_vjp_call",
                 "custom_vjp_call_jaxpr"):
         closed = (eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
@@ -812,7 +813,7 @@ def _select_n(self: _Interp, env, eqn, ctx, ins, out):
     i = getattr(self, "_cur_idx", 0)
     pred_eqn = _Interp._producer(jaxpr_eqns, pred_var, i)
     if pred_eqn is None and len(cases) == 2 and not _is_literal(pred_var):
-        # The jnp.where pjit shape: the select's predicate is a jaxpr invar
+        # The jnp.where nested-jit shape: the select's predicate is a jaxpr invar
         # whose producing comparison sits in the PARENT jaxpr. Guard
         # provenance recorded at the cmp crosses the call edge via aliases.
         info = self.guard_of.get(self._resolve(pred_var))
@@ -1151,8 +1152,7 @@ def analyze(fn: Callable, args, *, name: str, scale: SymbolicScale,
         return jax.make_jaxpr(fn)(*args)
 
     if x64:
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             closed = trace()
     else:
         closed = trace()

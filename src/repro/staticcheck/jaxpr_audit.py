@@ -4,7 +4,7 @@ repo's device-discipline rules on every sub-jaxpr.
 This generalizes the ad-hoc walker that used to live inline in
 ``tests/test_device_csr.py``: given a callable + example args, walk ALL
 sub-jaxprs (while_loop/scan/cond bodies, pallas_call kernels, nested
-pjit regions) and apply pluggable rules:
+nested jit regions) and apply pluggable rules:
 
 * ``no_dense_intermediate(max_elems)`` — no intermediate array at or
   above a size budget. This is how O(n²) staging regressions (the dense
@@ -54,7 +54,7 @@ _HOST_PRIMS = frozenset({"device_put", "infeed", "outfeed", "host_call"})
 
 def iter_subjaxprs(jaxpr) -> Iterator:
     """Yield ``jaxpr`` and every sub-jaxpr reachable through eqn params
-    (while/scan/cond branches, pjit regions, pallas kernels, ...)."""
+    (while/scan/cond branches, nested jit regions, pallas kernels, ...)."""
     yield jaxpr
     for eqn in jaxpr.eqns:
         for val in eqn.params.values():

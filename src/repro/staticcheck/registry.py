@@ -107,12 +107,10 @@ def _audit_halo_pipeline_sharded(fast: bool) -> list[Finding]:
     from repro.halos import halo_pipeline_sharded
 
     n = 128 if fast else 256
-    ndev = jax.local_device_count()
-    try:
-        mesh = jax.make_mesh((ndev,), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    except (AttributeError, TypeError):
-        mesh = jax.make_mesh((ndev,), ("data",))
+    # One device: the audit reads the staged jaxpr, and a process that has
+    # forced hundreds of host devices would not divide n.
+    mesh = jax.make_mesh((1,), ("data",), devices=jax.devices()[:1],
+                         axis_types=(jax.sharding.AxisType.Auto,))
     rng = np.random.default_rng(7)
     pts = np.sort(rng.uniform(0, 1, (n, 3)).astype(np.float32), axis=0)
     vel = rng.standard_normal((n, 3)).astype(np.float32)

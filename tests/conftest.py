@@ -26,14 +26,17 @@ except ModuleNotFoundError:
     sys.modules["hypothesis.strategies"] = _shim.strategies
 
 
-def abstract_mesh(sizes, names):
-    """AbstractMesh across JAX versions: <=0.4.x takes ((name, size), ...)
-    pairs; newer releases take (sizes, names)."""
-    import jax
-    try:
-        return jax.sharding.AbstractMesh(tuple(zip(names, sizes)))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(sizes), tuple(names))
+def load_chip_smoke():
+    """``chip_smoke.py`` (repo root) as a module; its phases import
+    ``benchmarks.common``, so the root goes on ``sys.path``."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(root, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def make_clustered_points(rng: np.random.Generator, n: int, d: int = 3,

@@ -166,7 +166,7 @@ def test_fixed_twin_clipped_gather_is_silent():
 
 
 def test_fixed_twin_f64_subtraction_meets_precision_floor():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         a = jnp.zeros((254,), jnp.float64)
         rep = analyze(lambda x, y: x - y, (a, a), name="cancel_f64",
                       scale=_scale(precision_floor=1e-3),
@@ -267,7 +267,7 @@ def test_csr_offsets_int64_past_2_31_at_mocked_large_counts():
     from repro.core.geometry import scene_bounds
     from repro.core.query import query_csr_device, within
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         pts = jnp.asarray(np.random.default_rng(0).random((4, 3)),
                           jnp.float32)
         lo, hi = scene_bounds(pts)
@@ -296,7 +296,7 @@ def test_csr_int64_requires_x64():
 def test_halo_catalog_labels_follow_int64_dtype():
     from repro.halos.catalog import canonicalize_labels, _sort_last
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         # global ids beyond 2^31: the int32 sort sentinel (2^31-1) would
         # sort REAL labels after noise
         big = 2**31 + 5

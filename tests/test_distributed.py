@@ -13,11 +13,8 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
     import numpy as np, jax, jax.numpy as jnp
-    try:  # axis_types only exists on newer JAX
-        mesh = jax.make_mesh(({n},), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    except (AttributeError, TypeError):
-        mesh = jax.make_mesh(({n},), ("data",))
+    mesh = jax.make_mesh(({n},), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     import sys
     sys.path.insert(0, "{tests}")
     from conftest import make_clustered_points
@@ -63,11 +60,8 @@ def test_distributed_cluster_spanning_all_shards():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import numpy as np, jax, jax.numpy as jnp
-        try:  # axis_types only exists on newer JAX
-            mesh = jax.make_mesh((8,), ("data",),
-                                 axis_types=(jax.sharding.AxisType.Auto,))
-        except (AttributeError, TypeError):
-            mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         from repro.core.distributed import dbscan_distributed
         n = 512
         x = np.linspace(0.01, 0.99, n).astype(np.float32)

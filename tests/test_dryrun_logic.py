@@ -4,22 +4,22 @@ from __future__ import annotations
 import numpy as np
 import jax
 import pytest
+from jax.sharding import AbstractMesh
 
 # NOTE: importing repro.launch.dryrun sets XLA_FLAGS; harmless here because
 # jax is already initialized with 1 device by the time tests import it.
-from conftest import abstract_mesh
 from repro.configs import ARCH_IDS, SHAPES, get_config, shapes_for
 from repro.launch import dryrun as dr
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 @pytest.fixture(scope="module")
 def multi_mesh():
-    return abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_input_specs_shapes():

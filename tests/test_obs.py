@@ -237,23 +237,18 @@ def test_registry_observe_known_types(tmp_path):
 def test_registry_shard_map_column():
     """Stats produced inside a shard_map region (with the cross-shard psum)
     aggregate in the registry to the same totals as the plain path."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     pts = _points(n=64, seed=4)
     bvh = _bvh(pts)
-    try:
-        mesh = jax.make_mesh((1,), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    except (AttributeError, TypeError):
-        mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
 
     def shard_fn(p):
         _, st = query_count(bvh, within(p, 0.2), with_stats=True)
         return st.psum("data")
 
-    stats = shard_map(shard_fn, mesh=mesh, in_specs=P("data"),
-                      out_specs=P("data"), check_rep=False)(jnp.asarray(pts))
+    stats = jax.shard_map(shard_fn, mesh=mesh, in_specs=P("data"),
+                          out_specs=P("data"), check_vma=False)(jnp.asarray(pts))
     reg = MetricsRegistry()
     reg.observe("sharded", stats)
     s = reg.summary()

@@ -31,11 +31,8 @@ _PRELUDE = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
     import numpy as np, jax, jax.numpy as jnp
-    try:  # axis_types only exists on newer JAX
-        mesh = jax.make_mesh(({n},), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    except (AttributeError, TypeError):
-        mesh = jax.make_mesh(({n},), ("data",))
+    mesh = jax.make_mesh(({n},), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 """)
 
 

@@ -5,20 +5,17 @@ from __future__ import annotations
 import numpy as np
 import jax
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.models.spec import TensorSpec
 from repro.parallel import sharding as shd
 
 
-from conftest import abstract_mesh
-
-
 @pytest.fixture(scope="module")
 def meshes():
     # 1-device meshes can't test divisibility; build ABSTRACT meshes instead.
-    single = abstract_mesh((16, 16), ("data", "model"))
-    multi = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    single = AbstractMesh((16, 16), ("data", "model"))
+    multi = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     return single, multi
 
 
@@ -100,7 +97,7 @@ def test_decode_score_pspec(meshes):
 def test_param_pspecs_tree():
     from repro.configs import get_config
     from repro.models import lm
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     spec = lm.model_spec(get_config("gemma2-9b"))
     pspecs = shd.param_pspecs(spec, mesh)
     # embed (256000, 3584): vocab/model, embed/data

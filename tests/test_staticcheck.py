@@ -54,7 +54,7 @@ def union_fixpoint(parent0):
 
 _R2_BAD_DECORATOR = """
 import jax, functools
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 @functools.partial(jax.jit, static_argnames=("n",))
 def driver(x, mesh, n):
     return shard_map(lambda a: a, mesh=mesh, in_specs=None, out_specs=None)(x)
@@ -62,14 +62,14 @@ def driver(x, mesh, n):
 
 _R2_BAD_CALL = """
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 def driver(x, mesh):
     return shard_map(lambda a: a, mesh=mesh, in_specs=None, out_specs=None)(x)
 run = jax.jit(driver)
 """
 
 _R2_OK_GATED = """
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core.distributed import _maybe_jit
 @_maybe_jit
 def driver(x, mesh):

@@ -196,3 +196,30 @@ def test_jit_and_grad_safe_composition():
     np.testing.assert_array_equal(
         np.asarray(f(bvh, pred)),
         np.asarray(query_count(bvh, pred, backend="stackless")))
+
+
+# --- no native lowering: a pointed error, never a silent interpret ----------
+
+def test_native_compile_raises_the_lowering_gap():
+    from repro.kernels.wavefront import LOWERING_GAP, wavefront_fill_round
+
+    pts = np.random.default_rng(0).uniform(0, 1, (64, 3)).astype(np.float32)
+    bvh = _bvh(pts)
+    qdata = (jnp.arange(64, dtype=jnp.int32),)
+    with pytest.raises(NotImplementedError, match="gather lowering"):
+        wavefront_traverse(bvh, qdata, lambda tree: (None, None),
+                           jnp.int32(0), interpret=False)
+    with pytest.raises(NotImplementedError, match="gather lowering"):
+        wavefront_fill_round(bvh, qdata, lambda tree: (None, None),
+                             jnp.zeros((64,), jnp.int32), 4, interpret=False)
+    assert "stackless" in LOWERING_GAP
+
+
+def test_engine_dispatch_raises_where_kernels_compile_natively(monkeypatch):
+    import sys
+    engine = sys.modules["repro.core.query"]  # the package re-exports query()
+    pts = np.random.default_rng(1).uniform(0, 1, (64, 3)).astype(np.float32)
+    monkeypatch.setattr(engine, "INTERPRET", False)
+    with pytest.raises(NotImplementedError, match="no native TPU lowering"):
+        query_count(_bvh(pts), within(jnp.asarray(pts), 0.1),
+                    backend="pallas")
