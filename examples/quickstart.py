@@ -107,7 +107,7 @@ assert bool(jnp.array_equal(counts_p, query_count(bvh, within(jp, eps),
 # result — per-query nodes visited, AABB/leaf tests, callback hits, early
 # exits and depth high-water mark — with ZERO cost when off (the stats-off
 # jaxpr is machine-checked identical to the uninstrumented engine):
-from repro.obs import MetricsRegistry, SpanTracer
+from repro.obs import SpanTracer
 
 counts_s, stats = query_count(bvh, within(jp, eps), stop_at=min_pts,
                               with_stats=True)
@@ -117,19 +117,15 @@ print(f"traversal: {int(tot['nodes_visited'])} nodes, "
       f"{int(tot['early_exits'])} early exits, depth {int(tot['max_depth'])}")
 
 # Host-side spans fence async dispatch (block_until_ready) so durations
-# cover the device work, and export Chrome-trace JSON for ui.perfetto.dev.
-# The sharded pipelines take `tracer=` directly (halo_pipeline_traced,
-# dbscan_distributed, InsituAnalyzer); a MetricsRegistry unifies the
-# engine's observability crumbs (CSR overflow/attempts, traversal stats):
+# cover the device work, and export Chrome-trace JSON for ui.perfetto.dev;
+# under jax.profiler.trace they also land in the profiler's trace, beside
+# the device ops, whose stages carry jax.named_scope names (bvh.build,
+# dbscan.union, ...). The sharded pipelines take `tracer=` directly
+# (halo_pipeline_traced, dbscan_distributed, InsituAnalyzer):
 tracer = SpanTracer()
 with tracer.span("quickstart_query", n=n) as sp:
     sp.fence(query_count(bvh, within(jp, eps)))
 tracer.export("trace_quickstart.json")      # load in ui.perfetto.dev
-
-reg = MetricsRegistry()
-reg.observe("quickstart/csr", dev)          # -> total + overflow series
-reg.observe("quickstart/query", stats)      # -> counter totals
-print(f"metrics: {sorted(reg.summary())}")
 
 # --- static checks ----------------------------------------------------------
 # The device-discipline rules this file leans on (no dense staging, no host

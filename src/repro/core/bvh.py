@@ -120,71 +120,74 @@ def build_bvh_objects(leaf_lo: jax.Array, leaf_hi: jax.Array,
     'it only requires bounding volumes for a set of objects'). Morton codes are
     taken from box centers. n must be >= 2."""
     n = leaf_lo.shape[0]
-    centers = (leaf_lo + leaf_hi) * 0.5
-    unit = _morton.normalize_points(centers, scene_lo, scene_hi)
+    with jax.named_scope("bvh.build"):
+        with jax.named_scope("bvh.morton_sort"):
+            centers = (leaf_lo + leaf_hi) * 0.5
+            unit = _morton.normalize_points(centers, scene_lo, scene_hi)
 
-    if use_64bit:
-        hi, lo = _morton.morton64(unit)
-        perm = _morton.sort_by_morton64(hi, lo).astype(jnp.int32)
-        hi_s, lo_s = hi[perm], lo[perm]
+            if use_64bit:
+                hi, lo = _morton.morton64(unit)
+                perm = _morton.sort_by_morton64(hi, lo).astype(jnp.int32)
+                hi_s, lo_s = hi[perm], lo[perm]
 
-        def delta(i, j):
-            return _morton.common_prefix_length64(hi_s, lo_s, jnp.asarray(i), jnp.asarray(j))
-    else:
-        codes = _morton.morton32(unit)
-        perm = _morton.sort_by_morton32(codes).astype(jnp.int32)
-        codes_s = codes[perm]
+                def delta(i, j):
+                    return _morton.common_prefix_length64(hi_s, lo_s, jnp.asarray(i), jnp.asarray(j))
+            else:
+                codes = _morton.morton32(unit)
+                perm = _morton.sort_by_morton32(codes).astype(jnp.int32)
+                codes_s = codes[perm]
 
-        def delta(i, j):
-            return _morton.common_prefix_length32(codes_s, jnp.asarray(i), jnp.asarray(j))
+                def delta(i, j):
+                    return _morton.common_prefix_length32(codes_s, jnp.asarray(i), jnp.asarray(j))
 
-    internal_ids = jnp.arange(n - 1, dtype=jnp.int32)
-    first, last, gamma = jax.vmap(_karras_ranges(delta))(internal_ids)
+        with jax.named_scope("bvh.hierarchy"):
+            internal_ids = jnp.arange(n - 1, dtype=jnp.int32)
+            first, last, gamma = jax.vmap(_karras_ranges(delta))(internal_ids)
 
-    # Children: leaf if the child range is a single leaf.
-    left = jnp.where(first == gamma, gamma + (n - 1), gamma)
-    right = jnp.where(last == gamma + 1, gamma + 1 + (n - 1), gamma + 1)
+            # Children: leaf if the child range is a single leaf.
+            left = jnp.where(first == gamma, gamma + (n - 1), gamma)
+            right = jnp.where(last == gamma + 1, gamma + 1 + (n - 1), gamma + 1)
 
-    # --- Ropes in closed form (see module docstring). ---
-    # split_node[g] = internal node whose split position is g.
-    split_node = jnp.zeros((n - 1,), jnp.int32).at[gamma].set(internal_ids)
-    split_end = jnp.zeros((n - 1,), jnp.int32).at[gamma].set(last)
+            # --- Ropes in closed form (see module docstring). ---
+            # split_node[g] = internal node whose split position is g.
+            split_node = jnp.zeros((n - 1,), jnp.int32).at[gamma].set(internal_ids)
+            split_end = jnp.zeros((n - 1,), jnp.int32).at[gamma].set(last)
 
-    def rope_of(end):  # end = inclusive leaf-range end of the node
-        is_last = end >= n - 1
-        end_c = jnp.clip(end, 0, n - 2)
-        p_end = split_end[end_c]
-        r = jnp.where(p_end == end + 1, end + 1 + (n - 1), end + 1)
-        return jnp.where(is_last, SENTINEL, r).astype(jnp.int32)
+            def rope_of(end):  # end = inclusive leaf-range end of the node
+                is_last = end >= n - 1
+                end_c = jnp.clip(end, 0, n - 2)
+                p_end = split_end[end_c]
+                r = jnp.where(p_end == end + 1, end + 1 + (n - 1), end + 1)
+                return jnp.where(is_last, SENTINEL, r).astype(jnp.int32)
 
-    rope_internal = rope_of(last)
-    rope_leaf = rope_of(jnp.arange(n, dtype=jnp.int32))
-    rope = jnp.concatenate([rope_internal, rope_leaf])
+            rope_internal = rope_of(last)
+            rope_leaf = rope_of(jnp.arange(n, dtype=jnp.int32))
+            rope = jnp.concatenate([rope_internal, rope_leaf])
 
-    # --- AABBs: leaves from points, internal via bottom-up fixpoint. ---
-    dim = leaf_lo.shape[1]
-    big = jnp.full((n - 1, dim), jnp.inf, leaf_lo.dtype)
-    node_lo0 = jnp.concatenate([big, leaf_lo[perm]])
-    node_hi0 = jnp.concatenate([-big, leaf_hi[perm]])
-    ready0 = jnp.concatenate([jnp.zeros(n - 1, bool), jnp.ones(n, bool)])
+            # --- AABBs: leaves from points, internal via bottom-up fixpoint. ---
+            dim = leaf_lo.shape[1]
+            big = jnp.full((n - 1, dim), jnp.inf, leaf_lo.dtype)
+            node_lo0 = jnp.concatenate([big, leaf_lo[perm]])
+            node_hi0 = jnp.concatenate([-big, leaf_hi[perm]])
+            ready0 = jnp.concatenate([jnp.zeros(n - 1, bool), jnp.ones(n, bool)])
 
-    def fix_cond(state):
-        _, _, ready = state
-        return ~jnp.all(ready)
+            def fix_cond(state):
+                _, _, ready = state
+                return ~jnp.all(ready)
 
-    def fix_body(state):
-        nlo, nhi, ready = state
-        l_lo, l_hi, l_rdy = nlo[left], nhi[left], ready[left]
-        r_lo, r_hi, r_rdy = nlo[right], nhi[right], ready[right]
-        new_lo = jnp.minimum(l_lo, r_lo)
-        new_hi = jnp.maximum(l_hi, r_hi)
-        ok = l_rdy & r_rdy
-        nlo = nlo.at[internal_ids].set(jnp.where(ok[:, None], new_lo, nlo[internal_ids]))
-        nhi = nhi.at[internal_ids].set(jnp.where(ok[:, None], new_hi, nhi[internal_ids]))
-        ready = ready.at[internal_ids].set(ready[internal_ids] | ok)
-        return nlo, nhi, ready
+            def fix_body(state):
+                nlo, nhi, ready = state
+                l_lo, l_hi, l_rdy = nlo[left], nhi[left], ready[left]
+                r_lo, r_hi, r_rdy = nlo[right], nhi[right], ready[right]
+                new_lo = jnp.minimum(l_lo, r_lo)
+                new_hi = jnp.maximum(l_hi, r_hi)
+                ok = l_rdy & r_rdy
+                nlo = nlo.at[internal_ids].set(jnp.where(ok[:, None], new_lo, nlo[internal_ids]))
+                nhi = nhi.at[internal_ids].set(jnp.where(ok[:, None], new_hi, nhi[internal_ids]))
+                ready = ready.at[internal_ids].set(ready[internal_ids] | ok)
+                return nlo, nhi, ready
 
-    node_lo, node_hi, _ = jax.lax.while_loop(fix_cond, fix_body, (node_lo0, node_hi0, ready0))
+            node_lo, node_hi, _ = jax.lax.while_loop(fix_cond, fix_body, (node_lo0, node_hi0, ready0))
 
     return Bvh(
         leaf_perm=perm,

@@ -86,10 +86,11 @@ def count_neighbors(bvh: Bvh, points: jax.Array, queries: jax.Array, eps,
 
 
 def _core_mask(bvh, points, eps, min_pts, early_stop=True, use_stack=False):
-    counts = count_neighbors(bvh, points, points, eps,
-                             min_pts=min_pts if early_stop else None,
-                             use_stack=use_stack)
-    return counts >= min_pts
+    with jax.named_scope("dbscan.core_pass"):
+        counts = count_neighbors(bvh, points, points, eps,
+                                 min_pts=min_pts if early_stop else None,
+                                 use_stack=use_stack)
+        return counts >= min_pts
 
 
 # ---------------------------------------------------------------------------
@@ -123,11 +124,12 @@ def _min_core_label_pass(bvh, points, eps, parent, core, queries_mask, n):
 
 
 def _finish_labels(parent, border_candidate, core, n):
-    labels = jnp.where(core, parent, jnp.where(border_candidate < n, border_candidate, NOISE))
-    # Border candidates were captured against possibly-stale parents; chase.
-    labels_safe = jnp.where(labels >= 0, labels, jnp.arange(n, dtype=jnp.int32))
-    resolved = union_find.compress(jnp.where(core, parent, labels_safe).astype(jnp.int32))
-    return jnp.where(labels >= 0, resolved, NOISE).astype(jnp.int32)
+    with jax.named_scope("dbscan.finish"):
+        labels = jnp.where(core, parent, jnp.where(border_candidate < n, border_candidate, NOISE))
+        # Border candidates were captured against possibly-stale parents; chase.
+        labels_safe = jnp.where(labels >= 0, labels, jnp.arange(n, dtype=jnp.int32))
+        resolved = union_find.compress(jnp.where(core, parent, labels_safe).astype(jnp.int32))
+        return jnp.where(labels >= 0, resolved, NOISE).astype(jnp.int32)
 
 
 def union_rounds(bvh, points, eps, core, n, max_rounds=64):
@@ -153,7 +155,9 @@ def union_rounds(bvh, points, eps, core, n, max_rounds=64):
         parent2 = union_find.compress(parent2)
         return parent2, jnp.any(parent2 != parent), r + 1
 
-    parent, _, rounds = jax.lax.while_loop(cond, body, (parent0, jnp.bool_(True), jnp.int32(0)))
+    with jax.named_scope("dbscan.union"):
+        parent, _, rounds = jax.lax.while_loop(
+            cond, body, (parent0, jnp.bool_(True), jnp.int32(0)))
     return parent, rounds
 
 
@@ -170,7 +174,8 @@ def fdbscan(points: jax.Array, eps, min_pts: int, *, early_stop: bool = True,
 
     core = _core_mask(bvh, points, eps, min_pts, early_stop=early_stop, use_stack=use_stack)
     parent, rounds = _union_rounds(bvh, points, eps, core, n)
-    border = _min_core_label_pass(bvh, points, eps, parent, core, ~core, n)
+    with jax.named_scope("dbscan.border_pass"):
+        border = _min_core_label_pass(bvh, points, eps, parent, core, ~core, n)
     labels = _finish_labels(parent, border, core, n)
     return DbscanResult(labels=labels, core_mask=core, num_rounds=rounds)
 
@@ -195,22 +200,25 @@ def dbscan_graph_cc(points: jax.Array, eps, min_pts: int,
 
     # The engine's fixed-capacity output protocol IS the documented
     # drawback: surplus neighbors overwrite the last slot.
-    nbrs, counts, _overflow = query_fixed(
-        bvh, within(points, jnp.asarray(eps, points.dtype)),
-        capacity=neighbor_capacity)
-    core = counts >= min_pts
+    with jax.named_scope("dbscan.core_pass"):
+        nbrs, counts, _overflow = query_fixed(
+            bvh, within(points, jnp.asarray(eps, points.dtype)),
+            capacity=neighbor_capacity)
+        core = counts >= min_pts
 
     # Core-core edges from the stored graph.
-    src = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[:, None], nbrs.shape)
-    valid = (nbrs >= 0) & core[src] & core[jnp.clip(nbrs, 0, n - 1)]
-    parent = union_find.connected_components(n, src.ravel(), jnp.clip(nbrs, 0, n - 1).ravel(),
-                                             valid.ravel())
-    parent = jnp.where(core, parent, jnp.arange(n, dtype=jnp.int32))
+    with jax.named_scope("dbscan.union"):
+        src = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[:, None], nbrs.shape)
+        valid = (nbrs >= 0) & core[src] & core[jnp.clip(nbrs, 0, n - 1)]
+        parent = union_find.connected_components(n, src.ravel(), jnp.clip(nbrs, 0, n - 1).ravel(),
+                                                 valid.ravel())
+        parent = jnp.where(core, parent, jnp.arange(n, dtype=jnp.int32))
 
     # Border: min core-neighbor root from the stored graph.
-    nbr_safe = jnp.clip(nbrs, 0, n - 1)
-    cand = jnp.where((nbrs >= 0) & core[nbr_safe], parent[nbr_safe], n)
-    border = jnp.min(cand, axis=1).astype(jnp.int32)
+    with jax.named_scope("dbscan.border_pass"):
+        nbr_safe = jnp.clip(nbrs, 0, n - 1)
+        cand = jnp.where((nbrs >= 0) & core[nbr_safe], parent[nbr_safe], n)
+        border = jnp.min(cand, axis=1).astype(jnp.int32)
     labels = _finish_labels(parent, border, core, n)
     return DbscanResult(labels=labels, core_mask=core, num_rounds=jnp.int32(1))
 
@@ -268,11 +276,13 @@ def fdbscan_pair(points: jax.Array, eps, min_pts: int,
         return parent2, jnp.any(parent2 != parent), overflow, r + 1
 
     parent0 = jnp.arange(n, dtype=jnp.int32)
-    parent, _, _, rounds = jax.lax.while_loop(
-        cond, body, (parent0, jnp.bool_(True), jnp.bool_(True), jnp.int32(0)))
+    with jax.named_scope("dbscan.union"):
+        parent, _, _, rounds = jax.lax.while_loop(
+            cond, body, (parent0, jnp.bool_(True), jnp.bool_(True), jnp.int32(0)))
     parent = jnp.where(core, parent, jnp.arange(n, dtype=jnp.int32))
 
-    border = _min_core_label_pass(bvh, points, eps, parent, core, ~core, n)
+    with jax.named_scope("dbscan.border_pass"):
+        border = _min_core_label_pass(bvh, points, eps, parent, core, ~core, n)
     labels = _finish_labels(parent, border, core, n)
     return DbscanResult(labels=labels, core_mask=core, num_rounds=rounds)
 
@@ -382,10 +392,11 @@ def fdbscan_densebox(points: jax.Array, eps, min_pts: int,
         return count, count >= min_pts
 
     # Queries only for loose (non-dense-cell) points, in grid-sorted order.
-    counts_s = query(bvh, within(pts_sorted, eps_f), count_cb, jnp.int32(0))
-    counts_s = jnp.where(~dense_s, counts_s, jnp.int32(0))
-    core_s = dense_s | (counts_s >= min_pts)
-    core = jnp.zeros(n, bool).at[grid.perm].set(core_s)
+    with jax.named_scope("dbscan.core_pass"):
+        counts_s = query(bvh, within(pts_sorted, eps_f), count_cb, jnp.int32(0))
+        counts_s = jnp.where(~dense_s, counts_s, jnp.int32(0))
+        core_s = dense_s | (counts_s >= min_pts)
+        core = jnp.zeros(n, bool).at[grid.perm].set(core_s)
 
     # --- Phase 2: union rounds. Pre-union dense cells to their min member. --
     seg_min_orig = seg_min_per_point(grid.perm, grid.run_start, grid.run_length)
@@ -453,11 +464,12 @@ def fdbscan_densebox(points: jax.Array, eps, min_pts: int,
         parent2 = union_find.compress(parent2)
         return parent2, jnp.any(parent2 != parent), r + 1
 
-    parent, _, rounds = jax.lax.while_loop(
-        cond, body, (union_find.compress(parent0), jnp.bool_(True), jnp.int32(0)))
+    with jax.named_scope("dbscan.union"):
+        parent, _, rounds = jax.lax.while_loop(
+            cond, body, (union_find.compress(parent0), jnp.bool_(True), jnp.int32(0)))
 
     # --- Border pass for non-core points. ---
-    border_s = min_label_pass(parent, ~core_s)
-    border = border_s  # already scattered back to original order
+    with jax.named_scope("dbscan.border_pass"):
+        border = min_label_pass(parent, ~core_s)  # scattered to original order
     labels = _finish_labels(parent, border, core, n)
     return DbscanResult(labels=labels, core_mask=core, num_rounds=rounds)

@@ -200,29 +200,32 @@ def halo_catalog(points: jax.Array, velocities: jax.Array, labels: jax.Array,
     ``min_pts`` for the paper's mass cut). ``backend``: "pallas" | "jax" |
     "auto" (Pallas on TPU, scatter oracle elsewhere).
     """
-    n, d = points.shape
-    sums, root_p, overflow, perm, pid_s, member_s = feature_sums(
-        points, velocities, labels, capacity=capacity, backend=backend)
-    (num_halos, root, count, mass, center, vmean, vdisp,
-     slot_of_prov) = derive_catalog(sums, root_p, min_count, particle_mass, d)
+    with jax.named_scope("halos.catalog"):
+        n, d = points.shape
+        sums, root_p, overflow, perm, pid_s, member_s = feature_sums(
+            points, velocities, labels, capacity=capacity, backend=backend)
+        (num_halos, root, count, mass, center, vmean, vdisp,
+         slot_of_prov) = derive_catalog(sums, root_p, min_count,
+                                        particle_mass, d)
 
-    # Second pass: max radius about the (provisional) center of mass.
-    cnt_f = sums[:, 0]
-    center_p = sums[:, 1:1 + d] / jnp.maximum(cnt_f, 1.0)[:, None]
-    r2_s = jnp.sum((points[perm].astype(jnp.float32) - center_p[pid_s]) ** 2,
-                   axis=-1)
-    r2_s = jnp.where(member_s, r2_s, -_kseg.SEG_NEG_BIG)
-    rmax2_p = _seg_max(r2_s[:, None], pid_s, capacity, backend)[:, 0]
-    rmax_p = jnp.sqrt(jnp.maximum(rmax2_p, 0.0))
-    # Route each surviving provisional halo's rmax to its compacted slot
-    # (cut halos collapse onto slot 0 with a harmless 0-valued max update).
-    rmax = jnp.zeros((capacity,), jnp.float32) \
-        .at[jnp.clip(slot_of_prov, 0, capacity - 1)] \
-        .max(jnp.where(slot_of_prov >= 0, rmax_p, 0.0))
+        # Second pass: max radius about the (provisional) center of mass.
+        cnt_f = sums[:, 0]
+        center_p = sums[:, 1:1 + d] / jnp.maximum(cnt_f, 1.0)[:, None]
+        r2_s = jnp.sum(
+            (points[perm].astype(jnp.float32) - center_p[pid_s]) ** 2,
+            axis=-1)
+        r2_s = jnp.where(member_s, r2_s, -_kseg.SEG_NEG_BIG)
+        rmax2_p = _seg_max(r2_s[:, None], pid_s, capacity, backend)[:, 0]
+        rmax_p = jnp.sqrt(jnp.maximum(rmax2_p, 0.0))
+        # Route each surviving provisional halo's rmax to its compacted slot
+        # (cut halos collapse onto slot 0 with a harmless 0-valued max update).
+        rmax = jnp.zeros((capacity,), jnp.float32) \
+            .at[jnp.clip(slot_of_prov, 0, capacity - 1)] \
+            .max(jnp.where(slot_of_prov >= 0, rmax_p, 0.0))
 
-    halo_s = jnp.where(member_s, slot_of_prov[pid_s], -1)
-    particle_halo = jnp.zeros((n,), jnp.int32).at[perm].set(halo_s)
+        halo_s = jnp.where(member_s, slot_of_prov[pid_s], -1)
+        particle_halo = jnp.zeros((n,), jnp.int32).at[perm].set(halo_s)
 
-    return HaloCatalog(num_halos=num_halos, overflow=overflow, root=root,
-                       count=count, mass=mass, center=center, vmean=vmean,
-                       vdisp=vdisp, rmax=rmax, particle_halo=particle_halo)
+        return HaloCatalog(num_halos=num_halos, overflow=overflow, root=root,
+                           count=count, mass=mass, center=center, vmean=vmean,
+                           vdisp=vdisp, rmax=rmax, particle_halo=particle_halo)

@@ -64,9 +64,10 @@ def halo_potentials(points: jax.Array, eps, *, softening=None,
     def fn(acc, _qi, _j, r2):
         return acc - jax.lax.rsqrt(r2 + soft2), jnp.bool_(False)
 
-    out = query(bvh, within(points.astype(jnp.float32), eps_f), fn,
-                jnp.float32(0.0))
-    return jnp.where(active, out, 0.0)
+    with jax.named_scope("halos.potential_pass"):
+        out = query(bvh, within(points.astype(jnp.float32), eps_f), fn,
+                    jnp.float32(0.0))
+        return jnp.where(active, out, 0.0)
 
 
 @partial(jax.jit, static_argnames=("capacity", "use_64bit"))
@@ -84,15 +85,18 @@ def most_bound_centers(points: jax.Array, particle_halo: jax.Array,
     member = particle_halo >= 0
     phi = halo_potentials(points, eps, softening=softening, active=member,
                           bvh=bvh, use_64bit=use_64bit)
-    slot = jnp.clip(particle_halo, 0, capacity - 1)
-    phi_masked = jnp.where(member, phi, _BIG)
-    min_phi = jnp.full((capacity,), _BIG, jnp.float32).at[slot].min(phi_masked)
-    attains = member & (phi_masked <= min_phi[slot])
-    idx = jnp.full((capacity,), n, jnp.int32).at[slot].min(
-        jnp.where(attains, jnp.arange(n, dtype=jnp.int32), n))
-    found = idx < n
-    idx_c = jnp.clip(idx, 0, n - 1)
-    center = jnp.where(found[:, None], points[idx_c].astype(jnp.float32), 0.0)
+    with jax.named_scope("halos.center_argmin"):
+        slot = jnp.clip(particle_halo, 0, capacity - 1)
+        phi_masked = jnp.where(member, phi, _BIG)
+        min_phi = jnp.full((capacity,), _BIG, jnp.float32).at[slot].min(
+            phi_masked)
+        attains = member & (phi_masked <= min_phi[slot])
+        idx = jnp.full((capacity,), n, jnp.int32).at[slot].min(
+            jnp.where(attains, jnp.arange(n, dtype=jnp.int32), n))
+        found = idx < n
+        idx_c = jnp.clip(idx, 0, n - 1)
+        center = jnp.where(found[:, None], points[idx_c].astype(jnp.float32),
+                           0.0)
     return MostBoundResult(
         index=jnp.where(found, idx, -1),
         center=center,

@@ -65,34 +65,36 @@ def so_masses_from_counts(count_fn, centers: jax.Array, valid: jax.Array, *,
     inside a ``shard_map`` region with zero host round-trips.
     ``n_particles`` is the GLOBAL particle count defining the reference
     density ``n × particle_mass / box_volume``."""
-    rho_ref = (jnp.asarray(delta, jnp.float32)
-               * n_particles * jnp.asarray(particle_mass, jnp.float32)
-               / jnp.asarray(box_volume, jnp.float32))
-    m = jnp.asarray(particle_mass, jnp.float32)
-    valid_f = valid.astype(jnp.float32)
+    with jax.named_scope("halos.so_bisect"):
+        rho_ref = (jnp.asarray(delta, jnp.float32)
+                   * n_particles * jnp.asarray(particle_mass, jnp.float32)
+                   / jnp.asarray(box_volume, jnp.float32))
+        m = jnp.asarray(particle_mass, jnp.float32)
+        valid_f = valid.astype(jnp.float32)
 
-    def body(_, state):
-        r_lo, r_hi = state
-        mid = 0.5 * (r_lo + r_hi)
-        cnt = count_fn(centers, mid * valid_f)
-        dens = cnt.astype(jnp.float32) * m \
-            / (_FOUR_THIRDS_PI * jnp.maximum(mid, 1e-12) ** 3)
-        above = dens >= rho_ref
-        return jnp.where(above, mid, r_lo), jnp.where(above, r_hi, mid)
+        def body(_, state):
+            r_lo, r_hi = state
+            mid = 0.5 * (r_lo + r_hi)
+            cnt = count_fn(centers, mid * valid_f)
+            dens = cnt.astype(jnp.float32) * m \
+                / (_FOUR_THIRDS_PI * jnp.maximum(mid, 1e-12) ** 3)
+            above = dens >= rho_ref
+            return jnp.where(above, mid, r_lo), jnp.where(above, r_hi, mid)
 
-    r0 = jnp.full((centers.shape[0],), jnp.asarray(r_max, jnp.float32))
-    r_lo, r_hi = jax.lax.fori_loop(0, iters, body,
-                                   (jnp.zeros_like(r0), r0))
-    r_delta = jnp.where(valid, r_lo, 0.0)
-    count = count_fn(centers, r_delta * valid_f)
-    count = jnp.where(valid, count, 0)
-    # Bracket check: did the density actually cross Δρ_ref inside [0, r_max]?
-    cnt_edge = count_fn(centers, r0 * valid_f)
-    dens_edge = cnt_edge.astype(jnp.float32) * m / (_FOUR_THIRDS_PI * r0 ** 3)
-    return SoMassResult(r_delta=r_delta,
-                        m_delta=count.astype(jnp.float32) * m,
-                        count=count,
-                        bracketed=valid & (dens_edge < rho_ref))
+        r0 = jnp.full((centers.shape[0],), jnp.asarray(r_max, jnp.float32))
+        r_lo, r_hi = jax.lax.fori_loop(0, iters, body,
+                                       (jnp.zeros_like(r0), r0))
+        r_delta = jnp.where(valid, r_lo, 0.0)
+        count = count_fn(centers, r_delta * valid_f)
+        count = jnp.where(valid, count, 0)
+        # Bracket check: did the density cross Δρ_ref inside [0, r_max]?
+        cnt_edge = count_fn(centers, r0 * valid_f)
+        dens_edge = (cnt_edge.astype(jnp.float32) * m
+                     / (_FOUR_THIRDS_PI * r0 ** 3))
+        return SoMassResult(r_delta=r_delta,
+                            m_delta=count.astype(jnp.float32) * m,
+                            count=count,
+                            bracketed=valid & (dens_edge < rho_ref))
 
 
 @partial(jax.jit, static_argnames=("iters", "use_64bit"))

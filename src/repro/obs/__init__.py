@@ -1,13 +1,14 @@
-"""Observability layer: device-side traversal stats, host-side span
-tracing with Chrome-trace export, and a unifying metrics registry.
+"""Observability layer: device-side traversal stats and host-side span
+tracing with Chrome-trace export.
 
-See ``obs/stats.py`` (TraversalStats), ``obs/trace.py`` (SpanTracer /
-traced), ``obs/metrics.py`` (MetricsRegistry). All three are strictly
-opt-in: the engine's stats-off path stages the identical jaxpr it did
-before this package existed (machine-checked by
-``repro.staticcheck``'s ``stats_path_identity`` audit).
+See ``obs/stats.py`` (TraversalStats) and ``obs/trace.py`` (SpanTracer /
+traced). Both are strictly opt-in: the engine's stats-off path stages the
+identical jaxpr it did before this package existed (machine-checked by
+``repro.staticcheck``'s ``stats_path_identity`` audit). Device-side, each
+stage of the library runs under a ``jax.named_scope`` (``bvh.build``,
+``dbscan.union``, ``halos.catalog``, ...), which names its ops in the
+compiled program's metadata and so in a profiler trace.
 """
-from repro.obs.metrics import MetricsRegistry
 from repro.obs.stats import TraversalStats
 from repro.obs.trace import (Span, SpanTracer, load_chrome_trace, span_tree,
                              traced)
@@ -19,5 +20,4 @@ __all__ = [
     "traced",
     "load_chrome_trace",
     "span_tree",
-    "MetricsRegistry",
 ]

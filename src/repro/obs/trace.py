@@ -6,7 +6,10 @@ FENCE — a pytree of device values that ``jax.block_until_ready`` drains
 before the span closes — so a span's duration covers the device work it
 launched. Spans nest through a plain stack; the export is Chrome trace
 event JSON (``{"traceEvents": [...]}``, "X" complete events), loadable
-directly in Perfetto (ui.perfetto.dev) or ``chrome://tracing``.
+directly in Perfetto (ui.perfetto.dev) or ``chrome://tracing``. Each span
+also opens a ``jax.profiler.TraceAnnotation`` of its name, so under
+``jax.profiler.trace`` the same spans land on the profiler's clock, in the
+trace that holds the device's ops.
 
 Usage::
 
@@ -44,6 +47,7 @@ class Span:
         self.args = args
         self.t0 = 0.0
         self._fences: list[Any] = []
+        self._annotation = jax.profiler.TraceAnnotation(name)
 
     def fence(self, value):
         """Register device values the span must drain before closing.
@@ -52,14 +56,18 @@ class Span:
         return value
 
     def __enter__(self) -> "Span":
+        self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        if exc_type is None:
-            for v in self._fences:
-                jax.block_until_ready(v)
-        self.tracer._close(self, time.perf_counter())
+        try:
+            if exc_type is None:
+                for v in self._fences:
+                    jax.block_until_ready(v)
+            self.tracer._close(self, time.perf_counter())
+        finally:
+            self._annotation.__exit__(exc_type, exc, tb)
 
 
 class SpanTracer:

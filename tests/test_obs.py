@@ -1,8 +1,11 @@
 """Observability layer (repro.obs): TraversalStats oracles vs brute force,
-span tracer nesting + Chrome-trace round trip, and metrics-registry
-aggregation (including per-shard columns from a shard_map region)."""
+span tracer nesting + Chrome-trace round trip and its spans in the
+profiler's trace, the library's device-side stage scopes in compiled HLO,
+and the totals of observability-bearing results (including per-shard
+columns from a shard_map region)."""
 from __future__ import annotations
 
+import re
 import time
 
 import numpy as np
@@ -19,7 +22,6 @@ from repro.core.query import (
     within,
 )
 from repro.obs import (
-    MetricsRegistry,
     Span,
     SpanTracer,
     TraversalStats,
@@ -202,41 +204,131 @@ def test_tracer_exception_unwind():
     assert tracer._stack == []
 
 
-# --- metrics registry -------------------------------------------------------
+# --- span tracer on the profiler's clock ----------------------------------
 
-def test_registry_aggregates_scalars_and_arrays():
-    reg = MetricsRegistry()
-    reg.record("x", 1)
-    reg.record("x", np.array([2.0, 3.0]))
-    reg.record("x", jnp.float32(4.0))
-    s = reg.summary()["x"]
-    assert s == {"records": 3, "count": 4, "sum": 10.0,
-                 "min": 1.0, "max": 4.0, "last": 4.0}
+def test_span_lands_in_profiler_trace(tmp_path):
+    """A span recorded under jax.profiler.trace is a host event of the
+    .xplane.pb, beside the device ops, and still exports to Chrome JSON."""
+    tracer = SpanTracer()
+    with jax.profiler.trace(str(tmp_path)):
+        with tracer.span("obs_test_outer"):
+            with tracer.span("obs_test_inner") as sp:
+                sp.fence(jnp.arange(16.0).sum())
+    files = sorted(tmp_path.rglob("*.xplane.pb"))
+    assert files
+    pd = jax.profiler.ProfileData.from_file(str(files[-1]))
+    host = {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+            for plane in pd.planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events}
+    assert {"obs_test_outer", "obs_test_inner"} <= set(host)
+    (o0, o1), (i0, i1) = host["obs_test_outer"], host["obs_test_inner"]
+    assert o0 <= i0 <= i1 <= o1
+    assert [e["name"] for e in tracer.events] == ["obs_test_inner",
+                                                 "obs_test_outer"]
 
 
-def test_registry_observe_known_types(tmp_path):
+# --- device-side stage scopes ----------------------------------------------
+
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_SCOPE = re.compile(r"^[a-z]+\.[a-z_]+$")
+
+STAGE_SCOPES = [
+    "bvh.build", "bvh.morton_sort", "bvh.hierarchy",
+    "dbscan.core_pass", "dbscan.union", "dbscan.border_pass",
+    "dbscan.finish",
+    "halos.catalog", "halos.potential_pass", "halos.center_argmin",
+    "halos.so_bisect",
+]
+DBSCAN_SCOPES = ["dbscan.core_pass", "dbscan.union", "dbscan.border_pass",
+                 "dbscan.finish"]
+
+
+def _hlo_scopes(text: str) -> set[str]:
+    """Library scopes named by the op_name metadata of an HLO module."""
+    return {c for m in _OP_NAME.finditer(text) for c in m.group(1).split("/")
+            if _SCOPE.match(c)}
+
+
+@pytest.fixture(scope="module")
+def step_hlo():
+    """Optimized HLO of the analysis step's library entry points, small n."""
+    from repro.core.dbscan import fdbscan
+    from repro.halos import halo_catalog, most_bound_centers, so_masses
+
+    n = 256
+    pts = jnp.asarray(_points(n=n, seed=5))
+    eps = np.float32(0.08)
+    lab = jax.ShapeDtypeStruct((n,), jnp.int32)
+    progs = {
+        "fdbscan": (lambda p, e: fdbscan(p, e, 2), (pts, eps)),
+        "halo_catalog": (lambda p, v, l: halo_catalog(
+            p, v, l, capacity=32, min_count=2), (pts, pts, lab)),
+        "most_bound_centers": (lambda p, h, e: most_bound_centers(
+            p, h, e, capacity=32), (pts, lab, eps)),
+        "so_masses": (lambda p, c, v: so_masses(p, c, v, iters=4),
+                      (pts, pts[:8], jnp.ones(8, bool))),
+    }
+    return {k: jax.jit(f).lower(*a).compile().as_text()
+            for k, (f, a) in progs.items()}
+
+
+@pytest.mark.parametrize("scope", STAGE_SCOPES)
+def test_stage_scope_in_compiled_step(step_hlo, scope):
+    assert scope in set().union(*map(_hlo_scopes, step_hlo.values()))
+
+
+def test_union_while_carries_scope(step_hlo):
+    """The union fixpoint's loop op itself, not only its body, carries
+    dbscan.union, so the loop's own device time counts toward it."""
+    whiles = [_OP_NAME.search(line).group(1)
+              for line in step_hlo["fdbscan"].splitlines()
+              if re.search(r"\swhile\(", line) and _OP_NAME.search(line)]
+    assert any(w.endswith("dbscan.union/while") for w in whiles)
+
+
+def test_build_sort_under_morton_sort(step_hlo):
+    """Each tree build sorts under bvh.morton_sort: fdbscan builds once."""
+    sorts = [_OP_NAME.search(line).group(1)
+             for line in step_hlo["fdbscan"].splitlines()
+             if re.search(r"\ssort\(", line) and _OP_NAME.search(line)]
+    assert len([s for s in sorts if "/bvh.morton_sort/" in s]) == 1
+
+
+@pytest.mark.parametrize("variant", ["fdbscan_pair", "fdbscan_densebox",
+                                     "dbscan_graph_cc"])
+def test_dbscan_variants_share_stage_scopes(variant):
+    from repro.core import dbscan
+
+    pts = jnp.asarray(_points(n=128, seed=6))
+    fn = getattr(dbscan, variant)
+    text = jax.jit(lambda p: fn(p, 0.1, 2)).lower(pts).compile().as_text()
+    assert set(DBSCAN_SCOPES) | {"bvh.build"} <= _hlo_scopes(text)
+
+
+# --- observability-bearing results, read directly -------------------------
+
+def test_registry_observe_known_types():
+    """DeviceCsr totals and TraversalStats.totals() agree with brute force;
+    the totals are device scalars until read."""
     pts = _points(n=64, seed=2)
     bvh = _bvh(pts)
+    want = float(_brute_counts(pts, 0.2).sum())
     csr = query_csr_device(bvh, within(jnp.asarray(pts), 0.2), capacity=4096)
     _, stats = query_count(bvh, within(jnp.asarray(pts), 0.2), with_stats=True)
 
-    reg = MetricsRegistry()
-    reg.observe("csr", csr)
-    reg.observe("q", stats)
-    s = reg.summary()
-    assert s["csr/total"]["last"] == float(_brute_counts(pts, 0.2).sum())
-    assert s["csr/overflowed"]["last"] == 0.0
-    assert s["q/callback_hits"]["sum"] == float(_brute_counts(pts, 0.2).sum())
-    assert s["q/nodes_visited"]["sum"] == (
-        s["q/aabb_tests"]["sum"] + s["q/leaf_tests"]["sum"])
-    out = reg.to_json(str(tmp_path / "metrics.json"))
-    import json
-    assert json.loads(open(out).read())["q/max_depth"]["last"] >= 1.0
+    assert float(csr.total) == want
+    assert not bool(csr.overflowed)
+    tot = stats.totals()
+    assert all(isinstance(v, jax.Array) for v in tot.values())
+    assert float(tot["callback_hits"]) == want
+    assert float(tot["nodes_visited"]) == (
+        float(tot["aabb_tests"]) + float(tot["leaf_tests"]))
+    assert float(tot["max_depth"]) >= 1.0
 
 
 def test_registry_shard_map_column():
-    """Stats produced inside a shard_map region (with the cross-shard psum)
-    aggregate in the registry to the same totals as the plain path."""
+    """Stats produced inside a shard_map region, reduced with .psum, total
+    the same as the plain path."""
     from jax.sharding import PartitionSpec as P
 
     pts = _points(n=64, seed=4)
@@ -249,8 +341,8 @@ def test_registry_shard_map_column():
 
     stats = jax.shard_map(shard_fn, mesh=mesh, in_specs=P("data"),
                           out_specs=P("data"), check_vma=False)(jnp.asarray(pts))
-    reg = MetricsRegistry()
-    reg.observe("sharded", stats)
-    s = reg.summary()
-    assert s["sharded/callback_hits"]["last"] == float(
+    _, plain = query_count(bvh, within(jnp.asarray(pts), 0.2), with_stats=True)
+    assert float(stats.totals()["callback_hits"]) == float(
         _brute_counts(pts, 0.2).sum())
+    for key, val in plain.totals().items():
+        assert float(stats.totals()[key]) == float(val), key
