@@ -6,6 +6,11 @@ capture — is one ``core.query`` engine call (``within`` predicates +
 fused callbacks / the fixed-capacity output protocol / the pair
 backend); this module only contributes the clustering logic around it.
 
+The min-label pass (``min_core_label_on``) traverses only the lanes its
+mask selects: they are compacted in tree order and walked in chunks of
+``LANE_CHUNK`` lanes, each chunk's lockstep loop ending at its own
+longest lane. Masked-out lanes are never traversed.
+
 Variants, matching the Fig. 4 improvement ladder:
 
 * ``dbscan_graph_cc``   — initial implementation (§4.3.1): materialize the
@@ -43,18 +48,25 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import union_find
-from repro.core.bvh import Bvh, build_bvh, build_bvh_objects
+from repro.core.bvh import SENTINEL, Bvh, build_bvh, build_bvh_objects
 from repro.core.cell_grid import CellGrid, build_cell_grid, cell_box
 from repro.core.geometry import scene_bounds as _scene
 from repro.core.query import query, query_count, query_fixed, within
 
 NOISE = jnp.int32(-1)
 
+# Lanes per chunk of a min-label pass: each chunk's lockstep loop runs to
+# its own longest lane. Chosen from a chip probe of the pass time against
+# the chunk size (PERF.md, PR 14).
+LANE_CHUNK = 256
+
 __all__ = [
     "NOISE",
+    "LANE_CHUNK",
     "DbscanResult",
     "count_neighbors",
     "min_core_label_on",
+    "traversed_lanes",
     "union_rounds",
     "dbscan_graph_cc",
     "fdbscan",
@@ -67,6 +79,8 @@ class DbscanResult(NamedTuple):
     labels: jax.Array       # (n,) int32; cluster root or -1 (noise)
     core_mask: jax.Array    # (n,) bool
     num_rounds: jax.Array   # () int32 — union fixpoint rounds taken
+    lane_share: jax.Array   # () float32 — lanes the min-label passes ran
+    #                         over (passes x n); 1.0 where not counted
 
 
 # ---------------------------------------------------------------------------
@@ -97,8 +111,21 @@ def _core_mask(bvh, points, eps, min_pts, early_stop=True, use_stack=False):
 # Min-label candidate traversal (shared by fdbscan variants)
 # ---------------------------------------------------------------------------
 
+def _lane_chunk(n: int) -> int:
+    return max(min(LANE_CHUNK, n), 1)
+
+
+def traversed_lanes(queries_mask: jax.Array) -> jax.Array:
+    """Lanes a min-label pass over ``queries_mask`` runs: the selected
+    lanes rounded up to whole chunks (int32)."""
+    c = _lane_chunk(queries_mask.shape[0])
+    active = jnp.sum(queries_mask, dtype=jnp.int32)
+    return (active + c - 1) // c * c
+
+
 def min_core_label_on(bvh: Bvh, query_pts: jax.Array, eps, obj_labels,
-                      obj_core, queries_mask, sentinel) -> jax.Array:
+                      obj_core, queries_mask, sentinel,
+                      order: jax.Array | None = None) -> jax.Array:
     """Engine pass shared by the FDBSCAN variants AND the distributed layer:
     for each query point with ``queries_mask`` set, the min over core
     ε-neighbor OBJECTS j of ``obj_labels[j]`` (``sentinel`` if none).
@@ -106,21 +133,47 @@ def min_core_label_on(bvh: Bvh, query_pts: jax.Array, eps, obj_labels,
     ``obj_labels`` / ``obj_core`` are indexed by the TREE's object index —
     decoupled from the query set, so the distributed layer can run local
     queries against a local ∪ ghost tree with exchanged ghost labels.
-    The sentinel follows ``obj_labels``'s dtype (int64 global ids at scale)."""
+    The sentinel follows ``obj_labels``'s dtype (int64 global ids at scale).
+
+    Masked-out queries are not traversed: a stable partition of ``order``
+    (default: the given order) puts the selected queries first, and they
+    are walked ``LANE_CHUNK`` at a time, each chunk's lockstep loop ending
+    at its own longest lane. Padding lanes of the last chunk start at
+    ``SENTINEL`` and never run. Masked-out slots hold ``sentinel``."""
     sentinel = jnp.asarray(sentinel, getattr(obj_labels, "dtype", jnp.int32))
+    n = query_pts.shape[0]
+    c = _lane_chunk(n)
+    padded = -(-n // c) * c
+    eps_q = jnp.asarray(eps, query_pts.dtype)
 
     def fn(best, _qi, j, _d2):
         return (jnp.where(obj_core[j], jnp.minimum(best, obj_labels[j]), best),
                 jnp.bool_(False))
 
-    out = query(bvh, within(query_pts, jnp.asarray(eps, query_pts.dtype)),
-                fn, sentinel)
-    return jnp.where(queries_mask, out, sentinel)
+    if order is None:
+        order = jnp.arange(n, dtype=jnp.int32)
+    sel = queries_mask[order]
+    active = jnp.sum(sel, dtype=jnp.int32)
+    slot = jnp.where(sel, jnp.cumsum(sel, dtype=jnp.int32) - 1, padded)
+    lanes = jnp.zeros((padded,), jnp.int32).at[slot].set(
+        order.astype(jnp.int32), mode="drop").reshape(-1, c)
+    live = (jnp.arange(padded, dtype=jnp.int32) < active).reshape(-1, c)
+
+    def chunk(k, out):
+        idx, on = lanes[k], live[k]
+        m = query(bvh, within(query_pts[idx], eps_q), fn, sentinel,
+                  start_nodes=jnp.where(on, 0, SENTINEL))
+        return out.at[jnp.where(on, idx, n)].set(m, mode="drop")
+
+    return jax.lax.fori_loop(0, traversed_lanes(sel) // c, chunk,
+                             jnp.full((n,), sentinel, sentinel.dtype))
 
 
 def _min_core_label_pass(bvh, points, eps, parent, core, queries_mask, n):
-    """Self-join wrapper: queries == objects == ``points``."""
-    return min_core_label_on(bvh, points, eps, parent, core, queries_mask, n)
+    """Self-join wrapper: queries == objects == ``points``, lanes in the
+    tree's leaf order."""
+    return min_core_label_on(bvh, points, eps, parent, core, queries_mask, n,
+                             order=bvh.leaf_perm)
 
 
 def _finish_labels(parent, border_candidate, core, n):
@@ -177,7 +230,13 @@ def fdbscan(points: jax.Array, eps, min_pts: int, *, early_stop: bool = True,
     with jax.named_scope("dbscan.border_pass"):
         border = _min_core_label_pass(bvh, points, eps, parent, core, ~core, n)
     labels = _finish_labels(parent, border, core, n)
-    return DbscanResult(labels=labels, core_mask=core, num_rounds=rounds)
+    # float32: rounds x lanes would wrap int32 at tens of millions of points
+    f32 = jnp.float32
+    lanes = (rounds.astype(f32) * traversed_lanes(core).astype(f32)
+             + traversed_lanes(~core).astype(f32))
+    lane_share = lanes / ((rounds + 1).astype(f32) * f32(n))
+    return DbscanResult(labels=labels, core_mask=core, num_rounds=rounds,
+                        lane_share=lane_share)
 
 
 # ---------------------------------------------------------------------------
@@ -220,7 +279,8 @@ def dbscan_graph_cc(points: jax.Array, eps, min_pts: int,
         cand = jnp.where((nbrs >= 0) & core[nbr_safe], parent[nbr_safe], n)
         border = jnp.min(cand, axis=1).astype(jnp.int32)
     labels = _finish_labels(parent, border, core, n)
-    return DbscanResult(labels=labels, core_mask=core, num_rounds=jnp.int32(1))
+    return DbscanResult(labels=labels, core_mask=core, num_rounds=jnp.int32(1),
+                        lane_share=jnp.float32(1.0))
 
 
 # ---------------------------------------------------------------------------
@@ -284,7 +344,8 @@ def fdbscan_pair(points: jax.Array, eps, min_pts: int,
     with jax.named_scope("dbscan.border_pass"):
         border = _min_core_label_pass(bvh, points, eps, parent, core, ~core, n)
     labels = _finish_labels(parent, border, core, n)
-    return DbscanResult(labels=labels, core_mask=core, num_rounds=rounds)
+    return DbscanResult(labels=labels, core_mask=core, num_rounds=rounds,
+                        lane_share=jnp.float32(1.0))
 
 
 # ---------------------------------------------------------------------------
@@ -472,4 +533,5 @@ def fdbscan_densebox(points: jax.Array, eps, min_pts: int,
     with jax.named_scope("dbscan.border_pass"):
         border = min_label_pass(parent, ~core_s)  # scattered to original order
     labels = _finish_labels(parent, border, core, n)
-    return DbscanResult(labels=labels, core_mask=core, num_rounds=rounds)
+    return DbscanResult(labels=labels, core_mask=core, num_rounds=rounds,
+                        lane_share=jnp.float32(1.0))

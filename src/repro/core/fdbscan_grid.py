@@ -191,7 +191,8 @@ def fdbscan_grid(points: jax.Array, eps, min_pts: int, *,
     resolved = union_find.compress(jnp.where(core, parent, jnp.where(border_ok, cand_safe, parent0)))
     labels = jnp.where(core | border_ok, resolved, NOISE).astype(jnp.int32)
 
-    return DbscanResult(labels=labels, core_mask=core, num_rounds=rounds), bins.overflowed
+    return (DbscanResult(labels=labels, core_mask=core, num_rounds=rounds,
+                         lane_share=jnp.float32(1.0)), bins.overflowed)
 
 
 class GridAutoInfo(NamedTuple):

@@ -254,7 +254,7 @@ class _Interp:
             return False
         outvars = getattr(self, "_cur_outvars", ())
         aliased = {var}
-        if any(v in aliased for v in outvars):
+        if any(not _is_literal(v) and v in aliased for v in outvars):
             return False
         used = False
         for eqn in eqns[self._cur_idx + 1:]:

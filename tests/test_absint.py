@@ -198,6 +198,15 @@ def test_negative_index_canonicalization_not_flagged():
     assert rep.findings == []
 
 
+def test_literal_program_output_does_not_stop_the_analysis():
+    # a jaxpr whose outputs include a constant (a Literal, unhashable) next
+    # to a signed integer op that overflows at symbolic N
+    rep = analyze(lambda a: (jnp.sum(a) * 3, jnp.float32(1.0)),
+                  (jnp.ones((254,), jnp.int32),), name="literal_out",
+                  scale=_scale(), input_ivals=[Ival(0, 2048)])
+    assert [f.rule for f in rep.findings] == ["W1-index-width"]
+
+
 def test_cross_pjit_where_refinement():
     # jnp.where stages a pjit: the select_n sits one jaxpr below the
     # comparison producing its predicate. The sentinel-guarded index must
